@@ -25,6 +25,9 @@ def main() -> None:
                              "query", "serve", "tenant"])
     args = ap.parse_args()
 
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
+
     from . import (bench_ann, bench_cluster, bench_ingest, bench_kde,
                    bench_kernels, bench_pipeline, bench_query, bench_serve,
                    bench_sharded, bench_tenant)

@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import lsh, sann, swakde
 
 
@@ -62,5 +63,6 @@ def sliding_window_kde():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     streaming_ann()
     sliding_window_kde()

@@ -110,12 +110,14 @@ def main():
     import jax.numpy as jnp
     import numpy as np
 
+    from repro.compile_cache import use_compile_cache
     from repro.configs import registry
     from repro.models import model as model_lib
     from repro.serve import kv_cache, serve_step as serve_lib
     from repro.serve.cluster import ClusterRetrievalService
     from repro.serve.retrieval import RetrievalConfig, RetrievalService
 
+    use_compile_cache()
     cfg = registry.get_smoke_config("qwen3-4b")
     retr_cfg = RetrievalConfig(
         dim=cfg.d_model, n_max=10_000, eta=0.3, r=0.35, c=2.0,

@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import lsh, swakde
 
 
@@ -51,4 +52,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

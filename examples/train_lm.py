@@ -10,6 +10,7 @@ import dataclasses
 
 import jax
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import registry
 from repro.data.pipeline import DataConfig
 from repro.train.trainer import Trainer, TrainerConfig
@@ -49,4 +50,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

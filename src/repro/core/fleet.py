@@ -42,7 +42,7 @@ import jax.numpy as jnp
 from . import lsh
 from .eh import eh_query_cells
 from .race import RACEState, estimate_from_vals
-from .sann import (SANNConfig, SANNState, _first_occurrence_mask,
+from .sann import (SANNConfig, SANNState, _first_occurrence_mask, bucket_cols,
                    sann_commit_chunk, sann_prepare_given_keep, sann_row_keys)
 from .swakde import (SWAKDEConfig, SWAKDEState, swakde_commit_chunk,
                      swakde_prepare_from_codes)
@@ -279,7 +279,8 @@ def sann_fleet_candidates(stacked: SANNState, params, qs: jax.Array,
     `sann_bucket_candidates_batch` on the request's own sketch."""
     codes = lsh.hash_points(params, qs)                         # (B, L)
     t = jnp.clip(tids, 0, stacked.n_seen.shape[0] - 1)
-    cand = stacked.tables[t[:, None], jnp.arange(cfg.L)[None, :], codes]
+    cand = stacked.tables[t[:, None, None], jnp.arange(cfg.L)[None, :, None],
+                          bucket_cols(codes, cfg.bucket_cap)]
     cand = cand.reshape(qs.shape[0], cfg.L * cfg.bucket_cap)
     ok = (cand >= 0) & stacked.valid[t[:, None], jnp.maximum(cand, 0)]
     return cand, ok, t

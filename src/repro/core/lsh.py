@@ -5,9 +5,12 @@ Two families, exactly the ones the paper uses:
   * p-stable Euclidean LSH [DIIM04]
 
 Both are expressed as pure-JAX pytrees + functions so they can live inside
-`vmap`/`scan`/`pjit`.  Hashing is a matmul (MXU-friendly); the Pallas kernel
-`repro.kernels.srp_hash` implements the hot path, and these functions are its
-reference semantics (`repro.kernels.ref` re-exports them).
+`vmap`/`scan`/`pjit`.  Hashing is a matmul (MXU-friendly), and these
+functions are the served path on every backend: ingest and query hash with
+them directly.  The Pallas kernel `repro.kernels.srp_hash` (with its oracle
+`repro.kernels.ref.srp_hash_ref`) is not called on that path; the TPU
+compiler refuses it (`kernels.dispatch.TPU_ROUTE`).  On a TPU the matmul
+runs at the backend's default float32 precision.
 
 A "hash function" here is always the paper's concatenated hash
 ``g(x) = (h_1(x), ..., h_k(x))`` folded to a bounded integer range via a

@@ -13,9 +13,13 @@ Faithful mechanics:
     results to running the per-query pipeline on each element.
 
 Hardware adaptation (DESIGN.md §5.2): pointer-chasing hash buckets become
-fixed-capacity **ring buffers** of point ids — `tables (L, n_buckets,
-bucket_cap)` — so insertion is a dense scatter and querying is a dense
-gather + one distance matmul (`repro.kernels.cand_score`).  The early-exit
+fixed-capacity **ring buffers** of point ids — `tables (L, n_buckets *
+bucket_cap)`, bucket ``c`` of row ``l`` at columns ``[c·cap, (c+1)·cap)`` —
+so insertion is a dense scatter and querying is a dense gather + one
+distance matmul (`repro.kernels.cand_score`).  The rings are flattened into
+one axis because a TPU pads an array's minor dimension to 128 lanes: a
+trailing ``bucket_cap = 16`` axis would take 8× its bytes in HBM (23 GB
+instead of 2.9 GB for the tables at n_max = 10M).  The early-exit
 ("stop at 3L") becomes a post-gather priority truncation: we score the same
 <=3L candidates the sequential algorithm would, Lemma 3.2's Markov bound is
 unchanged.
@@ -114,7 +118,8 @@ class SANNState(NamedTuple):
     write_ptr: jax.Array    # () int32 slot pointer, kept reduced mod capacity
     n_seen: jax.Array       # () int32, saturating (see core.util.saturating_add)
     n_stored: jax.Array     # () int32 — live stored points (== valid.sum())
-    tables: jax.Array       # (L, n_buckets, bucket_cap) int32 slot ids, -1 empty
+    tables: jax.Array       # (L, n_buckets * bucket_cap) int32 slot ids,
+    #   -1 empty; bucket c's ring is columns [c * bucket_cap, (c+1) * cap)
     table_ptr: jax.Array    # (L, n_buckets) int32 cyclic bucket pointers
     stamps: jax.Array       # (capacity,) int32 — logical arrival time of each
     #   stored point (its stream index, = n_seen at arrival; saturating like
@@ -132,7 +137,8 @@ def sann_empty_state(cfg: SANNConfig) -> SANNState:
         write_ptr=jnp.zeros((), jnp.int32),
         n_seen=jnp.zeros((), jnp.int32),
         n_stored=jnp.zeros((), jnp.int32),
-        tables=jnp.full((cfg.L, cfg.n_buckets, cfg.bucket_cap), -1, jnp.int32),
+        tables=jnp.full((cfg.L, cfg.n_buckets * cfg.bucket_cap), -1,
+                        jnp.int32),
         table_ptr=jnp.zeros((cfg.L, cfg.n_buckets), jnp.int32),
         stamps=jnp.full((cfg.capacity,), -1, jnp.int32),
     )
@@ -172,9 +178,10 @@ def sann_insert(state: SANNState, params, x: jax.Array, key: jax.Array,
 
     codes = lsh.hash_points(params, x)                          # (L,)
     rows = jnp.arange(cfg.L)
-    pos = state.table_ptr[rows, codes] % cfg.bucket_cap
-    old = tables[rows, codes, pos]
-    tables = tables.at[rows, codes, pos].set(
+    col = (codes * cfg.bucket_cap
+           + state.table_ptr[rows, codes] % cfg.bucket_cap)
+    old = tables[rows, col]
+    tables = tables.at[rows, col].set(
         jnp.where(keep, slot.astype(jnp.int32), old))
     table_ptr = state.table_ptr.at[rows, codes].add(jnp.where(keep, 1, 0))
 
@@ -357,14 +364,17 @@ def sann_commit_chunk(state: SANNState, prep: SANNPrep, cfg: SANNConfig,
     win_slot = jnp.where(prep.winner, slot, cap)             # OOB → dropped
 
     points = state.points.at[win_slot].set(prep.xs, mode="drop")
-    # Slots recycled this chunk form the ring interval
-    # [write_ptr + max(0, n_kept - cap), write_ptr + n_kept).
-    ring_off = (jnp.arange(cap, dtype=jnp.int32) - state.write_ptr) % cap
-    overwritten = ring_off < prep.n_kept
-    valid = state.valid | overwritten
+    def recycled(s):
+        # Slots recycled this chunk form the ring interval
+        # [write_ptr + max(0, n_kept - cap), write_ptr + n_kept).
+        return (s - state.write_ptr) % cap < prep.n_kept
+
+    valid = state.valid | recycled(jnp.arange(cap, dtype=jnp.int32))
 
     # --- tombstone stale references to every slot recycled this chunk ------
-    stale = (state.tables >= 0) & overwritten[jnp.maximum(state.tables, 0)]
+    # The interval test runs on the entries themselves: one elementwise
+    # pass over the tables, no gather.
+    stale = (state.tables >= 0) & recycled(state.tables)
     tables = jnp.where(stale, jnp.int32(-1), state.tables)
 
     # --- ring-buffer appends: prepared segment scatter ---------------------
@@ -503,6 +513,13 @@ def sann_delete(state: SANNState, params, x: jax.Array, cfg: SANNConfig,
                           n_stored=state.n_stored - hit.sum())
 
 
+def bucket_cols(codes: jax.Array, bucket_cap: int) -> jax.Array:
+    """Table columns of the buckets ``codes (..., L)`` → ``(..., L,
+    bucket_cap)``: the ring of bucket c is columns [c·cap, (c+1)·cap)."""
+    return (codes[..., None] * bucket_cap
+            + jnp.arange(bucket_cap, dtype=codes.dtype))
+
+
 class SANNResult(NamedTuple):
     index: jax.Array      # slot id of returned point (-1 = NULL)
     distance: jax.Array   # distance to returned point (inf = NULL)
@@ -522,7 +539,8 @@ def sann_bucket_candidates(state: SANNState, params, q: jax.Array,
     exact row-major order."""
     codes = lsh.hash_points(params, q)                          # (L,)
     rows = jnp.arange(cfg.L)
-    cand = state.tables[rows, codes].reshape(-1)                # (L*bucket_cap,)
+    cand = state.tables[rows[:, None],
+                        bucket_cols(codes, cfg.bucket_cap)].reshape(-1)
     ok = (cand >= 0) & state.valid[jnp.maximum(cand, 0)]
     return cand, ok
 
@@ -575,7 +593,8 @@ def sann_bucket_candidates_batch(state: SANNState, params, qs: jax.Array,
     `sann_bucket_candidates`, so the table-sharded path can all-gather
     per-shard blocks along axis 1 and reproduce the single-device order."""
     codes = lsh.hash_points(params, qs)                         # (B, L)
-    cand = state.tables[jnp.arange(cfg.L)[None, :], codes]      # (B, L, cap)
+    cand = state.tables[jnp.arange(cfg.L)[None, :, None],
+                        bucket_cols(codes, cfg.bucket_cap)]     # (B, L, cap)
     # explicit flat shape (not -1): keeps B = 0 batches reshapeable
     cand = cand.reshape(qs.shape[0], cfg.L * cfg.bucket_cap)
     ok = (cand >= 0) & state.valid[jnp.maximum(cand, 0)]
@@ -660,7 +679,8 @@ def sann_query_topk(state: SANNState, params, q: jax.Array, cfg: SANNConfig,
     exact."""
     codes = lsh.hash_points(params, q)
     rows = jnp.arange(cfg.L)
-    cand = state.tables[rows, codes].reshape(-1)
+    cand = state.tables[rows[:, None],
+                        bucket_cols(codes, cfg.bucket_cap)].reshape(-1)
     ok = (cand >= 0) & state.valid[jnp.maximum(cand, 0)]
     vecs = state.points[jnp.maximum(cand, 0)]
     d2 = jnp.where(ok, kernel_ops.cand_score(q, vecs), jnp.inf)

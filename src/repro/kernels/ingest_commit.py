@@ -14,16 +14,23 @@ Two kernels (DESIGN.md §12):
     each grid step owns one row's (n_buckets, bucket_cap) table block and
     replays its appends as one coalesced write pass.
 
-Both kernels follow the established dispatch contract (`kernels/ops.py`):
-TPU backends compile them; CPU runs the `ref.py` oracles (bit-identical —
-the engine paths never change semantics); ``interpret=None`` derives
-interpret mode from the backend, and tests/test_kernels.py pins
-interpret-mode equality against the oracles.
+Both kernels run only in interpret mode: tests/test_kernels.py pins their
+equality against the `ref.py` oracles, and every backend, the TPU included,
+runs the oracles (`dispatch.TPU_ROUTE`).
 
-TPU note: the EH tile shapes (levels, slots) are far below the fp32
-(8, 128) native tile, so a production TPU deployment would pad slots up to
-a lane multiple; the tiling structure (segments × levels ring walk,
-row-contiguous scatter) is what these kernels establish.
+TPU note: the TPU compiler refuses both at real widths (a described v5e,
+SW-AKDE at L=64, W=1024, chunk 4096; S-ANN at 16 tables of 2,845,244 ×
+16 slots):
+  * ``swakde_segment_pass`` — the ``(1, bg)`` segment blocks on ``(R, G)``
+    break the (8, 128) block rule.  With aligned ``(8, G)`` blocks the
+    body, which is `ref.swakde_segment_pass_ref`, fails next: Mosaic lowers
+    no shape-changing ``take_along_axis`` gather, and the pass materialises
+    ``(rows, G, C)`` intermediates (128 MiB per 8 rows) that exceed VMEM.
+  * ``sann_table_scatter`` — the ``(1, NB)`` pointer block on ``(L, NB)``
+    breaks the block rule, and one table row (182 MB) cannot be a VMEM
+    block at all; the 1-D ``(E,)`` entry blocks and the dynamic scalar
+    reads of the body come next.  A TPU form keeps the tables in HBM and
+    writes each entry by DMA.
 """
 from __future__ import annotations
 

@@ -1,7 +1,9 @@
 """Jitted public wrappers for the Pallas kernels.
 
 Dispatch policy (single source of truth: `dispatch.py`):
-  * on TPU backends → compiled Pallas kernels;
+  * on TPU backends → the op's entry in `dispatch.TPU_ROUTE`: the compiled
+    Pallas kernel, or the jitted `ref.py` oracle for kernels the TPU
+    compiler refuses;
   * on CPU → the pure-jnp oracle (`ref.py`) by default, because Pallas
     interpret mode is a Python-level emulator (correct but slow) — set
     ``REPRO_FORCE_PALLAS=1`` to route through interpret-mode kernels
@@ -27,13 +29,13 @@ def _interpret() -> bool:
 
 
 def srp_hash(x: jax.Array, proj: jax.Array, mix: jax.Array, n_buckets: int) -> jax.Array:
-    if _use_pallas():
+    if _use_pallas("srp_hash"):
         return _sh.srp_hash(x, proj, mix, n_buckets, interpret=_interpret())
     return ref.srp_hash_ref(x, proj, mix, n_buckets)
 
 
 def race_hist(codes: jax.Array, W: int) -> jax.Array:
-    if _use_pallas():
+    if _use_pallas("race_hist"):
         return _ru.race_hist(codes, W, interpret=_interpret())
     if W <= 128:
         # CPU mirror of the TPU kernel's one-hot compare + reduce (XLA CPU
@@ -56,7 +58,7 @@ def race_hist(codes: jax.Array, W: int) -> jax.Array:
 
 
 def cand_score(q: jax.Array, cands: jax.Array) -> jax.Array:
-    if _use_pallas():
+    if _use_pallas("cand_score"):
         return _cs.cand_score(q, cands, interpret=_interpret())
     return ref.cand_score_ref(q, cands)
 
@@ -67,18 +69,18 @@ def batch_score_topk(qs: jax.Array, cands: jax.Array, ok: jax.Array,
     against ``qs (B, d)`` → ``(d2 (B, k) ascending, idx (B, k) int32)``.
 
     k = 1 is the argmin of the (c, r)-query path.  TPU: one Pallas pass
-    (MXU matmul identity, running top-k across M tiles).  CPU: the
+    (diff-based distances, running top-k across M tiles).  CPU: the
     diff-based oracle — bit-identical to the per-query `cand_score` path,
     which is what makes the fused engine exactly match the vmapped oracle
     in tests/test_query_batched.py."""
-    if _use_pallas():
+    if _use_pallas("batch_score_topk"):
         return _bs.batch_score_topk(qs, cands, ok, k, interpret=_interpret())
     return ref.batch_score_topk_ref(qs, cands, ok, k)
 
 
 def sketch_decode_attn(q, k, v, block_ids, n_live, kv_len,
                        block_size: int = 512, softcap: float = 0.0) -> jax.Array:
-    if _use_pallas():
+    if _use_pallas("sketch_decode_attn"):
         return _sda.sketch_decode_attn(
             q, k, v, block_ids, n_live, kv_len,
             block_size=block_size, softcap=softcap, interpret=_interpret())
@@ -94,10 +96,11 @@ def swakde_segment_pass(cell_ts, cell_num, done, sorted_ts, seg_first,
                         cap: int = 0):
     """One expiry-free closed-form commit pass over the prepared segments
     (ingest tentpole — see `ref.swakde_segment_pass_ref` for the contract).
-    TPU: the tiled `ingest_commit.swakde_segment_pass` kernel; CPU: the
-    oracle, which is the fast path (one fused pass replaces the per-add
-    SumEH replay, independent of segment length)."""
-    if _use_pallas():
+    Both TPU (see `dispatch.TPU_ROUTE`) and CPU run the oracle: one fused
+    pass replaces the per-add SumEH replay, independent of segment length;
+    the tiled `ingest_commit.swakde_segment_pass` kernel runs only in
+    interpret mode."""
+    if _use_pallas("swakde_segment_pass"):
         return _ic.swakde_segment_pass(
             cell_ts, cell_num, done, sorted_ts, seg_first, seg_len,
             window=window, maxb=maxb, n_levels=n_levels, cap=cap,
@@ -109,11 +112,12 @@ def swakde_segment_pass(cell_ts, cell_num, done, sorted_ts, seg_first,
 
 def sann_table_scatter(tables, table_ptr, s_l, s_c, rank, val, mask):
     """Sorted-segment ring append into the S-ANN hash tables (entries are
-    sorted by (row, code); see `ref.sann_table_scatter_ref`)."""
-    if _use_pallas():
+    sorted by (row, code); see `ref.sann_table_scatter_ref`).  TPU and CPU
+    both run the oracle's XLA scatter (see `dispatch.TPU_ROUTE`)."""
+    if _use_pallas("sann_table_scatter"):
         return _ic.sann_table_scatter(
-            tables, table_ptr, s_l, s_c, rank, val, mask,
-            interpret=_interpret())
+            tables.reshape(*table_ptr.shape, -1), table_ptr, s_l, s_c, rank,
+            val, mask, interpret=_interpret()).reshape(tables.shape)
     return ref.sann_table_scatter_ref(tables, table_ptr, s_l, s_c, rank,
                                       val, mask)
 

@@ -3,11 +3,21 @@
 Given LSH codes (B, L) the sketch update increments ``counts[l, codes[b, l]]``
 for every (b, l).  Scatter is hostile to the VPU, so the TPU-native form is a
 **one-hot compare + reduce** per row tile, accumulated in a VMEM-resident
-(1, W) output block that the sequential grid revisits across batch chunks —
-a classic TPU histogram.
+output block that the sequential grid revisits across batch chunks — a
+classic TPU histogram.
 
-Grid: (L, ceil(B / CB)); the output row block is revisited for every batch
-chunk (TPU grids execute sequentially, so accumulation is safe).
+Layout: the codes are transposed to (L, B) so one row's codes lie along the
+lanes.  The one-hot tile of row l is ``hit[w, b] = (w == codes[l, b])``
+(buckets on sublanes, a sublane broadcast of the code row), and its lane
+sum is taken on the MXU as ``ones (8, CB) · hitᵀ`` — a transposed-RHS
+matmul whose first row is the histogram laid out along the lanes, ready to
+add into the (TL, TW) output block.  0/1 operands in bf16 and an f32
+accumulator keep every count exact (CB < 2^24).
+
+Grid: (L tiles, W tiles, B chunks), B innermost; the output block is
+revisited for every batch chunk (TPU grids execute sequentially, so
+accumulation is safe).  L pads to a multiple of 8, W to a multiple of 128
+and B to a multiple of the chunk; padded codes are -1 and match no bucket.
 """
 from __future__ import annotations
 
@@ -21,20 +31,27 @@ from jax.experimental import pallas as pl
 from .dispatch import resolve_interpret
 
 
-def _kernel(codes_ref, o_ref, *, B: int, cb: int):
-    bc = pl.program_id(1)
+def _kernel(codes_ref, o_ref, *, tl: int, tw: int):
+    bc = pl.program_id(2)
 
     @pl.when(bc == 0)
     def _():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    codes = codes_ref[...]                                   # (CB, 1) int32
-    W = o_ref.shape[1]
-    row = bc * cb + jax.lax.broadcasted_iota(jnp.int32, (cb, 1), 0)
-    valid = row < B                                          # mask batch padding
-    buckets = jax.lax.broadcasted_iota(jnp.int32, (cb, W), 1)
-    hit = (buckets == codes) & valid                         # (CB, W)
-    o_ref[...] += hit.astype(jnp.int32).sum(axis=0, keepdims=True)
+    cb = codes_ref.shape[1]
+    buckets = pl.program_id(1) * tw + jax.lax.broadcasted_iota(
+        jnp.int32, (tw, cb), 0)
+    ones = jnp.ones((8, cb), jnp.bfloat16)
+    for i in range(tl):
+        hit = (buckets == codes_ref[i:i + 1, :]).astype(jnp.bfloat16)
+        cnt = jax.lax.dot_general(
+            ones, hit, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)              # (8, TW)
+        o_ref[i:i + 1, :] += cnt[0:1, :].astype(jnp.int32)
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
 @functools.partial(jax.jit, static_argnames=("W", "block_b", "interpret"))
@@ -48,13 +65,22 @@ def race_hist(
     # None = derive from the backend, the same policy ops.py applies.
     interpret = resolve_interpret(interpret)
     B, L = codes.shape
-    cb = min(block_b, B)
-    grid = (L, pl.cdiv(B, cb))
-    return pl.pallas_call(
-        functools.partial(_kernel, B=B, cb=cb),
-        grid=grid,
-        in_specs=[pl.BlockSpec((cb, 1), lambda l, bc: (bc, l))],
-        out_specs=pl.BlockSpec((1, W), lambda l, bc: (l, 0)),
-        out_shape=jax.ShapeDtypeStruct((L, W), jnp.int32),
+    if B == 0:
+        return jnp.zeros((L, W), jnp.int32)
+    tl = 8
+    Lp = _round_up(L, tl)
+    Wp = _round_up(W, 128)
+    tw = next(t for t in (512, 256, 128) if Wp % t == 0)
+    cb = _round_up(min(block_b, B), 128)
+    Bp = _round_up(B, cb)
+    codes_t = jnp.pad(codes.astype(jnp.int32).T, ((0, Lp - L), (0, Bp - B)),
+                      constant_values=-1)                    # (Lp, Bp)
+    out = pl.pallas_call(
+        functools.partial(_kernel, tl=tl, tw=tw),
+        grid=(Lp // tl, Wp // tw, Bp // cb),
+        in_specs=[pl.BlockSpec((tl, cb), lambda l, w, bc: (l, bc))],
+        out_specs=pl.BlockSpec((tl, tw), lambda l, w, bc: (l, w)),
+        out_shape=jax.ShapeDtypeStruct((Lp, Wp), jnp.int32),
         interpret=interpret,
-    )(codes)
+    )(codes_t)
+    return out[:L, :W]

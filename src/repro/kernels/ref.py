@@ -138,8 +138,10 @@ def swakde_segment_pass_ref(
         pre = jnp.take_along_axis(
             P, jnp.clip(i - m0[..., None], 0, S - 1), -1)
         tpos = jnp.clip(b[..., None] + (i - K) * stride[..., None], 0, C - 1)
+        # Gather from the row's stamps directly: a (R, G, C) broadcast of
+        # them would be materialised (1 GiB at G=1024, C=4096) on the TPU.
         tail = jnp.take_along_axis(
-            jnp.broadcast_to(sorted_ts[:, None, :], (R, G, C)), tpos, -1)
+            sorted_ts, tpos.reshape(R, -1), axis=1).reshape(tpos.shape)
         return jnp.where(i < m0[..., None], ring,
                          jnp.where(i < K, pre, tail))
 
@@ -181,7 +183,7 @@ def swakde_segment_pass_ref(
 
 
 def sann_table_scatter_ref(
-    tables: jax.Array,     # (L, n_buckets, bucket_cap) int32
+    tables: jax.Array,     # (L, n_buckets * bucket_cap) int32 (or 3-D)
     table_ptr: jax.Array,  # (L, n_buckets) int32 — per-bucket ring pointers
     s_l: jax.Array,        # (B*L,) int32 — row of each sorted append
     s_c: jax.Array,        # (B*L,) int32 — bucket code of each append
@@ -193,8 +195,10 @@ def sann_table_scatter_ref(
     ``(table_ptr[s_l, s_c] + rank) % bucket_cap`` of its bucket; masked-out
     entries are dropped.  Entries are sorted by (row, code), so the writes
     of one bucket are contiguous — which is what the Pallas kernel tiles
-    over."""
-    L, n_buckets, bucket_cap = tables.shape
+    over.  ``tables`` may be flat per row or (L, n_buckets, bucket_cap):
+    both lay the rings out in the same row-major order."""
+    L, n_buckets = table_ptr.shape
+    bucket_cap = tables.size // (L * n_buckets)
     ring_pos = (table_ptr[s_l, s_c] + rank) % bucket_cap
     flat = (s_l * n_buckets + s_c) * bucket_cap + ring_pos
     tsize = jnp.int32(tables.size)

@@ -63,6 +63,12 @@ from repro.serve.kde_service import KDEService, KDEServiceConfig
 from repro.serve.race_service import RACEService, RACEServiceConfig
 from repro.serve.retrieval import RetrievalConfig, RetrievalService
 
+# JAX platform of spawned workers.  The coordinator's template engine
+# holds this process's device, and a chip belongs to one process at a
+# time, so workers run on the host CPU (ROADMAP Queue 2, "Clusters on
+# chips", would give each worker a chip of its own).
+WORKER_PLATFORM = "cpu"
+
 # `RemoteEngine._dur` sentinel: the failover layer only asks "is this
 # worker durable?" (`old._dur is not None`) — the actual durability config
 # lives in the worker process.
@@ -284,7 +290,8 @@ class _RPCClusterMixin:
         else:
             host = rc.host
             proc, port = W.spawn_worker(
-                self._service_kind, wcfg, host=host,
+                self._service_kind, wcfg, platform=WORKER_PLATFORM,
+                host=host,
                 spawn_timeout_s=rc.spawn_timeout_s)
         try:
             ch = self._connect(host, port, scope=f"worker_{w}/")

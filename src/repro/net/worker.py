@@ -25,7 +25,8 @@ coordinator process can never leave orphan workers behind.  The child
 binds an ephemeral port and hands it back over a pipe; service configs
 travel as plain dicts (`dataclasses.asdict`) and are rebuilt in the
 child, so the worker's engine is constructed from the exact same config
-the in-process oracle would use.
+the in-process oracle would use.  The coordinator picks the child's JAX
+platform and each worker reports the one it runs on in ``health()``.
 """
 from __future__ import annotations
 
@@ -170,9 +171,11 @@ class WorkerServer:
         raise P.ProtocolError(f"unknown request kind {kind}")
 
     def _health_meta(self) -> dict:
+        import jax
         eng = self.engine
         out = dict(eng.health())
         out["version"] = int(eng.version)
+        out["platform"] = jax.devices()[0].platform
         for extra in ("steps", "count", "stored"):
             try:
                 v = getattr(eng, extra)
@@ -205,11 +208,11 @@ def run_worker(service_kind: str, cfg_dict: dict, host: str = "127.0.0.1",
 
 
 def _worker_main(conn, service_kind: str, cfg_dict: dict,
-                 host: str) -> None:
-    """Spawned-child main: build engine, bind an ephemeral port, hand it
-    back over the pipe, serve.  Any startup failure travels back as a
-    traceback instead of a silent dead child."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+                 host: str, platform: str) -> None:
+    """Spawned-child main: pin JAX to ``platform``, build engine, bind an
+    ephemeral port, hand it back over the pipe, serve.  Any startup
+    failure travels back as a traceback instead of a silent dead child."""
+    os.environ["JAX_PLATFORMS"] = platform
     try:
         svc = build_service(service_kind, cfg_dict)
         srv = WorkerServer(svc, host=host, port=0)
@@ -230,18 +233,20 @@ def _worker_main(conn, service_kind: str, cfg_dict: dict,
             pass
 
 
-def spawn_worker(service_kind: str, cfg_dict: dict,
+def spawn_worker(service_kind: str, cfg_dict: dict, *, platform: str,
                  host: str = "127.0.0.1",
                  spawn_timeout_s: float = 300.0):
     """Start a worker process (spawn context, daemon) and wait for its
-    port.  Returns ``(process, port)``; on failure the child is reaped
-    before the error propagates (no orphan PIDs)."""
+    port.  ``platform`` is the child's ``JAX_PLATFORMS`` — the caller's
+    choice, since a chip belongs to one process at a time.  Returns
+    ``(process, port)``; on failure the child is reaped before the error
+    propagates (no orphan PIDs)."""
     import multiprocessing as mp
 
     ctx = mp.get_context("spawn")
     rx, tx = ctx.Pipe(duplex=False)
     proc = ctx.Process(target=_worker_main,
-                       args=(tx, service_kind, cfg_dict, host),
+                       args=(tx, service_kind, cfg_dict, host, platform),
                        daemon=True, name=f"sketch-worker-{service_kind}")
     proc.start()
     tx.close()

@@ -15,7 +15,7 @@ assumes for partitioned updates):
     per-row EH estimates (`core.swakde.swakde_row_estimates`).  Cross-
     *worker* combine (different sub-streams, same clock) is the exact EH
     merge `core.swakde.swakde_merge`.
-  * **S-ANN** — sharded **by table**: ``tables (L, n_buckets, cap)`` and
+  * **S-ANN** — sharded **by table**: ``tables (L, n_buckets * cap)`` and
     ``table_ptr`` split along L; the point store / valid mask / counters
     are replicated (every device makes the same keep decisions from the
     same key, so the replicas stay bit-identical by construction).  Queries
@@ -66,20 +66,12 @@ from .sharding import ShardingCtx, make_ctx
 
 SHARD_AXIS = "shard"
 
-try:  # jax >= 0.6: top-level shard_map, replication check kw is check_vma
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-except AttributeError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
-
 def _smap(fn, mesh, in_specs, out_specs):
     # Replication checking is disabled: outputs marked P() are replicated by
     # construction (identical per-device computation), which the static
     # checker cannot see through all_gather + gather chains.
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: False})
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -174,7 +166,7 @@ def _sann_state_specs(ctx: ShardingCtx):
     r = ctx.spec()
     return sann.SANNState(
         points=r, valid=r, write_ptr=r, n_seen=r, n_stored=r,
-        tables=ctx.spec("sketch_tables", None, None),
+        tables=ctx.spec("sketch_tables", None),
         table_ptr=ctx.spec("sketch_tables", None),
         stamps=r)
 

@@ -207,7 +207,7 @@ def test_sann_ring_eviction_tombstones_stale_entries():
         else:
             st = sann.sann_insert_batch(st0, p, xs, jax.random.PRNGKey(9), cfg)
         codes_all = np.asarray(lsh.hash_points(p, st.points))  # (capacity, L)
-        tables = np.asarray(st.tables)
+        tables = np.asarray(st.tables).reshape(cfg.L, cfg.n_buckets, -1)
         for l in range(cfg.L):
             tab = tables[l]                                    # (buckets, cap)
             mask = tab >= 0

@@ -81,6 +81,8 @@ def test_rpc_race_bit_exact_vs_inprocess_oracle():
                                 num_workers=2, merge_every=4)
     try:
         assert svc.workers[0]._ch.engine_kind == "RACEService"
+        # the coordinator picks the workers' platform: the host CPU
+        assert [w["platform"] for w in svc.health()["workers"]] == ["cpu"] * 2
         svc.ingest(data)
         np.testing.assert_array_equal(svc.query(qs), oracle.query(qs))
         np.testing.assert_array_equal(svc.kde(qs), oracle.kde(qs))

@@ -194,7 +194,7 @@ def test_sann_two_phase_eviction_tombstones_stale_entries():
         st = sann.sann_commit_chunk(
             st, sann.sann_prepare_chunk(p, xs[i:i + 100], k, cfg), cfg)
     codes_all = np.asarray(lsh.hash_points(p, st.points))      # (capacity, L)
-    tables = np.asarray(st.tables)
+    tables = np.asarray(st.tables).reshape(cfg.L, cfg.n_buckets, -1)
     for l in range(cfg.L):
         tab = tables[l]                                        # (buckets, cap)
         mask = tab >= 0
