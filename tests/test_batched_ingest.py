@@ -12,6 +12,8 @@ import pytest
 
 from repro.core import eh, lsh, race, sann, swakde
 
+import compiled
+
 
 def _states_equal(a, b):
     return all(
@@ -102,12 +104,13 @@ def test_sum_eh_closed_form_matches_ref():
     st_new = eh.sum_eh_init(cfg)
     for t in range(40):
         v = int(rng.integers(0, 65))
-        st_ref = eh.sum_eh_add_ref(st_ref, jnp.int32(t), jnp.int32(v), cfg)
-        st_new = eh.sum_eh_add(st_new, jnp.int32(t), jnp.int32(v), cfg)
+        t_, v_ = jnp.int32(t), jnp.int32(v)
+        st_ref = compiled.sum_eh_add_ref(st_ref, t_, v_, cfg)
+        st_new = compiled.sum_eh_add(st_new, t_, v_, cfg)
         assert (np.asarray(st_ref.num) == np.asarray(st_new.num)).all(), t
         assert (_live_masked(st_ref, cfg) == _live_masked(st_new, cfg)).all(), t
-        q_ref = float(eh.sum_eh_query(st_ref, jnp.int32(t), cfg))
-        q_new = float(eh.sum_eh_query(st_new, jnp.int32(t), cfg))
+        q_ref = float(compiled.sum_eh_query(st_ref, jnp.int32(t), cfg))
+        q_new = float(compiled.sum_eh_query(st_new, jnp.int32(t), cfg))
         assert q_ref == q_new, (t, q_ref, q_new)
 
 

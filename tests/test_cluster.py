@@ -28,7 +28,7 @@ from repro.serve.retrieval import RetrievalConfig, RetrievalService
 _RACE_KW = dict(dim=8, L=6, W=32, ingest_chunk=64, seed=3)
 # window >= stream: nothing expires -> the merged EH structure (and so the
 # estimates) is bit-exact vs single-engine; expiry degrades to the usual
-# estimate-level EH-merge guarantee (see test_distributed merge tests).
+# estimate-level EH-merge guarantee (see test_merge.py).
 _KDE_KW = dict(dim=8, L=6, W=32, window=100_000, eh_eps=0.2, ingest_chunk=50)
 # eta=0: keep prob n^0 = 1, so keep decisions are key-independent and the
 # single-engine reference makes the same ones; stream < capacity, so no

@@ -1,155 +1,10 @@
-"""Multi-device semantics (subprocesses with 8 virtual CPU devices):
-sharded step == single-device step; EP MoE == dense MoE; compressed DP
-all-reduce ≈ exact with error feedback; sketch sharding
-(repro.parallel.sketch_sharding) == single-device sketches bit-for-bit;
-sketch merges are associative.
+"""Multi-device sketch semantics (subprocesses with 8 virtual CPU
+devices): sketch sharding (repro.parallel.sketch_sharding) == single-device
+sketches bit-for-bit, sharded S-ANN merge included.  The single-device merge
+semantics are in test_merge.py, the multi-device training semantics in
+test_distributed_train.py.
 """
-import os
-import subprocess
-import sys
-import textwrap
-
-import pytest
-
-
-def _run(body: str) -> str:
-    script = ("import os\n"
-              "os.environ['XLA_FLAGS'] = "
-              "'--xla_force_host_platform_device_count=8'\n" +
-              textwrap.dedent(body))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
-    env.pop("JAX_PLATFORMS", None)
-    r = subprocess.run([sys.executable, "-c", script], capture_output=True,
-                       text=True, timeout=480)
-    assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-3000:])
-    return r.stdout
-
-
-def test_sharded_train_step_matches_single_device():
-    out = _run("""
-        import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.configs import registry
-        from repro.launch.inputs import make_batch
-        from repro.launch.mesh import make_test_mesh
-        from repro.optim.adam import adamw
-        from repro.parallel import param_specs
-        from repro.train.train_step import make_train_step
-        import dataclasses
-
-        cfg = dataclasses.replace(registry.get_smoke_config("qwen3-4b"),
-                                  param_dtype="float32", seq_parallel=True)
-        key = jax.random.PRNGKey(0)
-        batch = make_batch(cfg, batch=8, seq=32, key=jax.random.PRNGKey(1))
-
-        # single device
-        step1, init1 = make_train_step(cfg, adamw(lr=1e-3), mesh=None)
-        s1 = init1(key)
-        s1, m1 = jax.jit(step1)(s1, batch)
-
-        # 2x4 mesh with full sharding machinery
-        mesh = make_test_mesh(2, 4)
-        stepN, initN = make_train_step(cfg, adamw(lr=1e-3), mesh=mesh)
-        sN = initN(key)
-        p_sh = param_specs.param_shardings(sN.params, mesh)
-        o_sh = param_specs.opt_state_shardings(sN.opt_state, p_sh, mesh)
-        state_sh = type(sN)(params=p_sh, opt_state=o_sh,
-                            step=NamedSharding(mesh, P()))
-        b_sh = param_specs.batch_shardings(batch, mesh)
-        sN = jax.device_put(sN, state_sh)
-        batchN = jax.device_put(batch, b_sh)
-        sN, mN = jax.jit(stepN, in_shardings=(state_sh, b_sh),
-                         donate_argnums=(0,))(sN, batchN)
-
-        print("loss1", float(m1["loss"]), "lossN", float(mN["loss"]))
-        assert abs(float(m1["loss"]) - float(mN["loss"])) < 2e-4
-        for a, b in zip(jax.tree.leaves(s1.params), jax.tree.leaves(sN.params)):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=2e-4, atol=2e-5)
-        print("SHARDED_MATCH_OK")
-    """)
-    assert "SHARDED_MATCH_OK" in out
-
-
-def test_moe_ep_matches_dense():
-    out = _run("""
-        import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.launch.mesh import make_test_mesh
-        from repro.models import moe
-        from repro.parallel.sharding import make_ctx
-
-        key = jax.random.PRNGKey(0)
-        d, E, k = 32, 8, 2
-        p = moe.init_moe(key, d, E, n_shared=1, d_ff_expert=16,
-                         dtype=jnp.float32)
-        x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, d))
-        y_dense, aux_d = moe.moe_dense(p, x, topk=k, capacity_factor=64.0)
-
-        mesh = make_test_mesh(2, 4)
-        ctx = make_ctx(mesh)
-        # place params per the EP layout (experts on model, FSDP on data)
-        p_ep = dict(p)
-        p_ep["w_gate"] = jax.device_put(p["w_gate"], NamedSharding(mesh, P("model", "data", None)))
-        p_ep["w_up"] = jax.device_put(p["w_up"], NamedSharding(mesh, P("model", "data", None)))
-        p_ep["w_down"] = jax.device_put(p["w_down"], NamedSharding(mesh, P("model", None, "data")))
-        x_ep = jax.device_put(x, NamedSharding(mesh, P("data", None, None)))
-        y_ep, aux_e = jax.jit(lambda p, x: moe.moe_ep(
-            p, x, topk=k, capacity_factor=64.0, ctx=ctx))(p_ep, x_ep)
-
-        # EP combines its psum in bf16 (§Perf hillclimb 2) — equivalence
-        # holds to bf16 rounding of the routed-expert contribution
-        np.testing.assert_allclose(np.asarray(y_dense), np.asarray(y_ep),
-                                   rtol=2e-2, atol=2e-2)
-        assert float(jnp.abs(jnp.asarray(y_dense) - jnp.asarray(y_ep)).mean()) < 5e-3
-        # aux is a per-shard load-balance estimate (Switch-style): close but
-        # not identical to the global product
-        np.testing.assert_allclose(float(aux_d), float(aux_e), rtol=5e-2)
-        print("MOE_EP_OK")
-    """)
-    assert "MOE_EP_OK" in out
-
-
-def test_compressed_allreduce_error_feedback():
-    out = _run("""
-        import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import PartitionSpec as P
-        from repro.launch.mesh import make_test_mesh
-        from repro.optim import compress
-
-        mesh = jax.make_mesh((8,), ("data",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-        g = jax.random.normal(jax.random.PRNGKey(0), (8, 4, 130))
-
-        def worker(g_local, e_local):
-            (r, e2) = compress.compressed_pmean(
-                {"w": g_local}, compress.ErrorState(err={"w": e_local}), "data")
-            return r["w"], e2.err["w"]
-
-        f = jax.jit(jax.shard_map(worker, mesh=mesh,
-                                  in_specs=(P("data"), P("data")),
-                                  out_specs=(P("data"), P("data")),
-                                  check_vma=False))
-        exact = jnp.broadcast_to(g.mean(0, keepdims=True), g.shape)
-        err = jnp.zeros_like(g)
-        # accumulate over steps: error feedback keeps the running sum honest
-        tot_comp = jnp.zeros_like(g[0])
-        tot_exact = jnp.zeros_like(g[0])
-        for i in range(20):
-            red, err = f(g * (1 + 0.01 * i), err)
-            tot_comp = tot_comp + red[0]
-            tot_exact = tot_exact + (g * (1 + 0.01 * i)).mean(0)
-        one_step_err = float(jnp.abs(red[0] - (g * 1.19).mean(0)).max())
-        accum_err = float(jnp.abs(tot_comp - tot_exact).max())
-        rel = accum_err / float(jnp.abs(tot_exact).max())
-        print("one-step", one_step_err, "accum rel", rel)
-        assert rel < 0.02  # error feedback keeps long-run bias tiny
-        exact_b, comp_b = compress.bytes_saved_per_step({"w": g[0]})
-        assert comp_b < 0.3 * exact_b
-        print("COMPRESS_OK")
-    """)
-    assert "COMPRESS_OK" in out
+from multidevice import run_script
 
 
 # ---------------------------------------------------------------------------
@@ -158,7 +13,7 @@ def test_compressed_allreduce_error_feedback():
 # ---------------------------------------------------------------------------
 
 def test_sharded_race_matches_single_device():
-    out = _run("""
+    out = run_script("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import lsh, race
         from repro.parallel import sketch_sharding as ss
@@ -168,26 +23,30 @@ def test_sharded_race_matches_single_device():
         xs = jax.random.normal(jax.random.PRNGKey(1), (300, d))
         qs = jax.random.normal(jax.random.PRNGKey(2), (7, d))
 
-        ref = race.race_update_batch(race.race_init(L, W), params, xs)
-        ref = race.race_update_batch(ref, params, xs[:50], sign=-1)  # turnstile
+        ref = jitted(race.race_update_batch)(race.race_init(L, W), params, xs)
+        ref = jitted(race.race_update_batch, sign=-1)(  # turnstile
+            ref, params, xs[:50])
 
         ctx = ss.make_sketch_ctx(ss.make_sketch_mesh(8))
         st, p = ss.shard_race(race.race_init(L, W), params, ctx)
-        st = ss.sharded_race_update_batch(st, p, xs, ctx)
-        st = ss.sharded_race_update_batch(st, p, xs[:50], ctx, sign=-1)
+        st = jitted(ss.sharded_race_update_batch, ctx=ctx)(st, p, xs)
+        st = jitted(ss.sharded_race_update_batch, ctx=ctx, sign=-1)(
+            st, p, xs[:50])
         assert (np.asarray(st.counts) == np.asarray(ref.counts)).all()
         assert int(st.n) == int(ref.n)
         for mom in (0, 4):
             np.testing.assert_array_equal(
-                np.asarray(ss.sharded_race_query_batch(st, p, qs, ctx, mom)),
-                np.asarray(race.race_query_batch(ref, params, qs, mom)))
+                np.asarray(jitted(ss.sharded_race_query_batch, ctx=ctx,
+                                  median_of_means=mom)(st, p, qs)),
+                np.asarray(jitted(race.race_query_batch,
+                                  median_of_means=mom)(ref, params, qs)))
         print("RACE_SHARDED_OK")
     """)
     assert "RACE_SHARDED_OK" in out
 
 
 def test_sharded_swakde_matches_single_device():
-    out = _run("""
+    out = run_script("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import lsh, swakde
         from repro.parallel import sketch_sharding as ss
@@ -198,18 +57,22 @@ def test_sharded_swakde_matches_single_device():
         xs = jax.random.normal(jax.random.PRNGKey(1), (250, d))
         qs = jax.random.normal(jax.random.PRNGKey(2), (6, d))
 
+        update = jitted(swakde.swakde_update_chunk, cfg=cfg)
         ref = swakde.swakde_init(cfg)
         for i in range(0, 250, 100):   # uneven final chunk on purpose
-            ref = swakde.swakde_update_chunk(ref, params, xs[i:i+100], cfg)
+            ref = update(ref, params, xs[i:i+100])
 
         ctx = ss.make_sketch_ctx(ss.make_sketch_mesh(8))
+        sharded_update = jitted(ss.sharded_swakde_update_chunk, cfg=cfg,
+                                ctx=ctx)
         st, p = ss.shard_swakde(swakde.swakde_init(cfg), params, ctx)
         for i in range(0, 250, 100):
-            st = ss.sharded_swakde_update_chunk(st, p, xs[i:i+100], cfg, ctx)
+            st = sharded_update(st, p, xs[i:i+100])
         for a, b in zip(jax.tree.leaves(st), jax.tree.leaves(ref)):
             assert (np.asarray(a) == np.asarray(b)).all()
         np.testing.assert_array_equal(
-            np.asarray(ss.sharded_swakde_query_batch(st, p, qs, cfg, ctx)),
+            np.asarray(jitted(ss.sharded_swakde_query_batch, cfg=cfg,
+                              ctx=ctx)(st, p, qs)),
             np.asarray(swakde.swakde_query_batch(ref, params, qs, cfg)))
         print("SWAKDE_SHARDED_OK")
     """)
@@ -217,7 +80,7 @@ def test_sharded_swakde_matches_single_device():
 
 
 def test_sharded_sann_matches_single_device():
-    out = _run("""
+    out = run_script("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import sann
         from repro.parallel import sketch_sharding as ss
@@ -231,23 +94,29 @@ def test_sharded_sann_matches_single_device():
         key = jax.random.PRNGKey(2)
         qs = stream[:9] + 0.01
 
-        ref = sann.sann_insert_batch(st0, params, stream, key, cfg)
-        ref = sann.sann_delete(ref, params, stream[3], cfg)
+        ref = jitted(sann.sann_insert_batch, cfg=cfg)(st0, params, stream,
+                                                      key)
+        ref = jitted(sann.sann_delete, cfg=cfg)(ref, params, stream[3])
 
         ctx = ss.make_sketch_ctx(ss.make_sketch_mesh(8))
         st, p = ss.shard_sann(st0, params, ctx)
-        st = ss.sharded_sann_insert_batch(st, p, stream, key, cfg, ctx)
-        st = ss.sharded_sann_delete(st, p, stream[3], cfg, ctx)
+        st = jitted(ss.sharded_sann_insert_batch, cfg=cfg, ctx=ctx)(
+            st, p, stream, key)
+        st = jitted(ss.sharded_sann_delete, cfg=cfg, ctx=ctx)(st, p,
+                                                              stream[3])
         for nm, a, b in zip(ref._fields, st, ref):
             assert (np.asarray(a) == np.asarray(b)).all(), nm
 
-        r_ref = sann.sann_query_batch(ref, params, qs, cfg)
-        r_sh = ss.sharded_sann_query_batch(st, p, qs, cfg, ctx)
+        r_ref = jitted(sann.sann_query_batch, cfg=cfg)(ref, params, qs)
+        r_sh = jitted(ss.sharded_sann_query_batch, cfg=cfg, ctx=ctx)(st, p,
+                                                                     qs)
         for nm, a, b in zip(r_ref._fields, r_sh, r_ref):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                           err_msg=nm)
-        tk_ref = sann.sann_query_topk_batch(ref, params, qs, cfg, topk=10)
-        tk_sh = ss.sharded_sann_query_topk_batch(st, p, qs, cfg, ctx, topk=10)
+        tk_ref = jitted(sann.sann_query_topk_batch, cfg=cfg, topk=10)(
+            ref, params, qs)
+        tk_sh = jitted(ss.sharded_sann_query_topk_batch, cfg=cfg, ctx=ctx,
+                       topk=10)(st, p, qs)
         np.testing.assert_array_equal(np.asarray(tk_sh[0]),
                                       np.asarray(tk_ref[0]))
         np.testing.assert_array_equal(np.asarray(tk_sh[1]),
@@ -263,7 +132,7 @@ def test_sharded_two_phase_matches_single_device():
     sketches; the sharded grid-estimate table (the KDE service's snapshot
     cache) matches the single-device table and its reads match the fused
     query path."""
-    out = _run("""
+    out = run_script("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import lsh, race, sann, swakde
         from repro.parallel import sketch_sharding as ss
@@ -277,12 +146,12 @@ def test_sharded_two_phase_matches_single_device():
         params = lsh.init_srp(jax.random.PRNGKey(0), d, L=L, k=2, n_buckets=W)
         ref = race.race_update_batch(race.race_init(L, W), params, xs[:130])
         ref = race.race_update_batch(ref, params, xs[130:], sign=-1)
+        prepare = jitted(ss.sharded_race_prepare_chunk, n_buckets=W, ctx=ctx)
         st, p = ss.shard_race(race.race_init(L, W), params, ctx)
-        st = ss.sharded_race_commit_chunk(
-            st, ss.sharded_race_prepare_chunk(p, xs[:130], W, ctx), ctx)
-        st = ss.sharded_race_commit_chunk(
-            st, ss.sharded_race_prepare_chunk(p, xs[130:], W, ctx), ctx,
-            sign=-1)
+        st = jitted(ss.sharded_race_commit_chunk, ctx=ctx)(
+            st, prepare(p, xs[:130]))
+        st = jitted(ss.sharded_race_commit_chunk, ctx=ctx, sign=-1)(
+            st, prepare(p, xs[130:]))
         assert (np.asarray(st.counts) == np.asarray(ref.counts)).all()
         assert int(st.n) == int(ref.n)
 
@@ -291,24 +160,26 @@ def test_sharded_two_phase_matches_single_device():
         cfg = swakde.SWAKDEConfig(L=8, W=32, window=120, eh_eps=0.15)
         params = lsh.init_srp(jax.random.PRNGKey(2), d, L=8, k=2,
                               n_buckets=32)
+        update = jitted(swakde.swakde_update_chunk, cfg=cfg)
         ref = swakde.swakde_init(cfg)
         for i in range(0, 230, 100):   # uneven final chunk on purpose
-            ref = swakde.swakde_update_chunk(ref, params, xs[i:i+100], cfg)
+            ref = update(ref, params, xs[i:i+100])
+        prepare = jitted(ss.sharded_swakde_prepare_chunk, cfg=cfg, ctx=ctx)
+        commit = jitted(ss.sharded_swakde_commit_chunk, cfg=cfg, ctx=ctx)
         st, p = ss.shard_swakde(swakde.swakde_init(cfg), params, ctx)
-        preps = [ss.sharded_swakde_prepare_chunk(p, xs[i:i+100], cfg, ctx)
-                 for i in range(0, 230, 100)]
+        preps = [prepare(p, xs[i:i+100]) for i in range(0, 230, 100)]
         for prep in preps:
-            st = ss.sharded_swakde_commit_chunk(st, prep, cfg, ctx)
+            st = commit(st, prep)
         for a, b in zip(jax.tree.leaves(st), jax.tree.leaves(ref)):
             assert (np.asarray(a) == np.asarray(b)).all()
-        grid = ss.sharded_swakde_grid_estimates(st, cfg, ctx)
+        grid = jitted(ss.sharded_swakde_grid_estimates, cfg=cfg, ctx=ctx)(st)
         np.testing.assert_array_equal(
             np.asarray(grid), np.asarray(swakde.swakde_grid_estimates(ref,
                                                                       cfg)))
         qs = xs[:7]
         np.testing.assert_array_equal(
-            np.asarray(ss.sharded_swakde_query_from_grid(grid, p, qs, cfg,
-                                                         ctx)),
+            np.asarray(jitted(ss.sharded_swakde_query_from_grid, cfg=cfg,
+                              ctx=ctx)(grid, p, qs)),
             np.asarray(swakde.swakde_query_batch(ref, params, qs, cfg)))
 
         # S-ANN: sharded prepare -> commit under ring wrap vs single-device
@@ -318,15 +189,15 @@ def test_sharded_two_phase_matches_single_device():
         stream = jnp.asarray(np.random.default_rng(4).uniform(
             0, 1, (600, d)).astype(np.float32))
         ckeys = jax.random.split(jax.random.PRNGKey(5), 3)
+        insert = jitted(sann.sann_insert_batch, cfg=cfg)
         ref = st0
         for i, k in zip(range(0, 600, 200), ckeys):
-            ref = sann.sann_insert_batch(ref, params, stream[i:i+200], k,
-                                         cfg)
+            ref = insert(ref, params, stream[i:i+200], k)
+        prepare = jitted(ss.sharded_sann_prepare_chunk, cfg=cfg, ctx=ctx)
+        commit = jitted(ss.sharded_sann_commit_chunk, cfg=cfg, ctx=ctx)
         st, p = ss.shard_sann(st0, params, ctx)
         for i, k in zip(range(0, 600, 200), ckeys):
-            prep = ss.sharded_sann_prepare_chunk(p, stream[i:i+200], k, cfg,
-                                                 ctx)
-            st = ss.sharded_sann_commit_chunk(st, prep, cfg, ctx)
+            st = commit(st, prepare(p, stream[i:i+200], k))
         for nm, a, b in zip(ref._fields, st, ref):
             assert (np.asarray(a) == np.asarray(b)).all(), nm
         print("TWO_PHASE_SHARDED_OK")
@@ -335,7 +206,7 @@ def test_sharded_two_phase_matches_single_device():
 
 
 def test_sharded_services_match_single_device():
-    out = _run("""
+    out = run_script("""
         import numpy as np
         from repro.serve.retrieval import RetrievalConfig, RetrievalService
         from repro.serve.kde_service import KDEServiceConfig, KDEService
@@ -365,304 +236,8 @@ def test_sharded_services_match_single_device():
     assert "SERVICES_SHARDED_OK" in out
 
 
-# ---------------------------------------------------------------------------
-# Merge APIs (multi-worker ingestion): single-device semantics, no mesh.
-# ---------------------------------------------------------------------------
-
-def test_race_merge_associative_commutative():
-    import jax
-    import numpy as np
-    from repro.core import lsh, race
-
-    params = lsh.init_srp(jax.random.PRNGKey(0), 8, L=6, k=2, n_buckets=16)
-    xs = jax.random.normal(jax.random.PRNGKey(1), (240, 8))
-    parts = [race.race_update_batch(race.race_init(6, 16), params, xs[i::3])
-             for i in range(3)]
-    a, b, c = parts
-
-    def eq(x, y):
-        return all((np.asarray(u) == np.asarray(v)).all()
-                   for u, v in zip(jax.tree.leaves(x), jax.tree.leaves(y)))
-
-    assert eq(race.race_merge(race.race_merge(a, b), c),
-              race.race_merge(a, race.race_merge(b, c)))
-    assert eq(race.race_merge(a, b), race.race_merge(b, a))
-    # merge of partition == one sketch over the whole stream (any order)
-    whole = race.race_update_batch(race.race_init(6, 16), params, xs)
-    merged = race.race_merge(race.race_merge(a, b), c)
-    assert (np.asarray(merged.counts) == np.asarray(whole.counts)).all()
-    assert int(merged.n) == int(whole.n)
-
-
-def test_swakde_merge_semantics():
-    """swakde_merge is the exact EH bucket-union merge: commutative bitwise,
-    mass-preserving, associative at the estimate level (bucket *structure*
-    may differ by one cascade level between groupings), and a merge of
-    sketches over disjoint sub-streams estimates the sum of their windows."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from repro.core import lsh, swakde
-
-    d = 8
-    # window < substream length, so cells hold buckets at the expiry
-    # boundary (ts == t - 1 - window) — the case where the merge clock must
-    # match the query clock (state.t - 1), not state.t.
-    cfg = swakde.SWAKDEConfig(L=6, W=24, window=80, eh_eps=0.2)
-    params = lsh.init_srp(jax.random.PRNGKey(0), d, L=6, k=2, n_buckets=24)
-    xs = jax.random.normal(jax.random.PRNGKey(1), (300, d))
-    qs = jax.random.normal(jax.random.PRNGKey(2), (5, d))
-
-    # three workers over disjoint sub-streams, same shared clock length
-    parts = [swakde.swakde_update_chunk(swakde.swakde_init(cfg), params,
-                                        xs[i::3], cfg) for i in range(3)]
-    a, b, c = parts
-
-    ab = swakde.swakde_merge(a, b, cfg)
-    ba = swakde.swakde_merge(b, a, cfg)
-    for u, v in zip(jax.tree.leaves(ab), jax.tree.leaves(ba)):
-        assert (np.asarray(u) == np.asarray(v)).all()   # commutative bitwise
-
-    ab_c = swakde.swakde_merge(ab, c, cfg)
-    a_bc = swakde.swakde_merge(a, swakde.swakde_merge(b, c, cfg), cfg)
-
-    def mass(st):
-        eh = cfg.eh_config()
-        idx = np.arange(eh.slots)[None, :]
-        ts, num = np.asarray(st.ts), np.asarray(st.num)
-        # live at the query clock t - 1, the convention every reader uses
-        live = (idx < num[..., None]) & (ts > int(st.t) - 1 - cfg.window)
-        sizes = (1 << np.arange(eh.levels))[:, None]
-        return (live * sizes).sum(axis=(-2, -1))
-
-    np.testing.assert_array_equal(mass(ab_c), mass(a_bc))  # mass exact
-    e1 = np.asarray(swakde.swakde_query_batch(ab_c, params, qs, cfg))
-    e2 = np.asarray(swakde.swakde_query_batch(a_bc, params, qs, cfg))
-    np.testing.assert_allclose(e1, e2, rtol=2 * cfg.kde_eps, atol=1.0)
-
-    # identity: merging with an empty sketch changes no *live* state — the
-    # merge may compact buckets `a` only expires lazily, so the invariant
-    # is estimate equality (bitwise) + in-window mass, not raw num equality
-    empty = swakde.swakde_init(cfg)._replace(t=a.t)
-    ida = swakde.swakde_merge(a, empty, cfg)
-    np.testing.assert_array_equal(
-        np.asarray(swakde.swakde_query_batch(ida, params, qs, cfg)),
-        np.asarray(swakde.swakde_query_batch(a, params, qs, cfg)))
-    np.testing.assert_array_equal(mass(ida), mass(a))
-
-    # disjoint-substream correctness: merged window ≈ sum of part windows
-    est_m = np.asarray(swakde.swakde_query_batch(ab_c, params, qs, cfg))
-    est_sum = sum(np.asarray(swakde.swakde_query_batch(p_, params, qs, cfg))
-                  for p_ in parts)
-    np.testing.assert_allclose(est_m, est_sum,
-                               rtol=3 * cfg.kde_eps, atol=1.5)
-
-
-def test_merge_fuzz_identity_and_associativity():
-    """Property fuzz over randomized 3-way stream splits (PR-5 satellite):
-
-      * RACE — empty merge is the bitwise identity; associativity holds
-        bitwise for every random split;
-      * SW-AKDE — empty merge is a live-state identity (estimates + mass);
-        associativity is bit-exact at the estimate level while nothing has
-        expired (window >= stream), and mass-exact always.
-    """
-    import jax
-    import numpy as np
-    from repro.core import lsh, race, swakde
-
-    d = 6
-    params = lsh.init_srp(jax.random.PRNGKey(0), d, L=4, k=2, n_buckets=16)
-    cfg = swakde.SWAKDEConfig(L=4, W=16, window=100_000, eh_eps=0.25)
-    qs = np.asarray(jax.random.normal(jax.random.PRNGKey(9), (4, d)))
-
-    def eq(x, y):
-        return all((np.asarray(u) == np.asarray(v)).all()
-                   for u, v in zip(jax.tree.leaves(x), jax.tree.leaves(y)))
-
-    for seed in range(4):
-        rng = np.random.default_rng(seed)
-        xs = rng.normal(0, 1, (180, d)).astype(np.float32)
-        pid = rng.integers(0, 3, len(xs))        # randomized 3-way split
-
-        # --- RACE: bitwise identity + associativity ------------------------
-        parts = [race.race_update_batch(race.race_init(4, 16), params,
-                                        xs[pid == w]) for w in range(3)]
-        a, b, c = parts
-        assert eq(race.race_merge(a, race.race_init(4, 16)), a)
-        assert eq(race.race_merge(race.race_init(4, 16), a), a)
-        assert eq(race.race_merge(race.race_merge(a, b), c),
-                  race.race_merge(a, race.race_merge(b, c)))
-
-        # --- SW-AKDE: live-state identity + estimate associativity ---------
-        sparts = [swakde.swakde_update_chunk(swakde.swakde_init(cfg), params,
-                                             xs[pid == w], cfg)
-                  for w in range(3)]
-        sa, sb, sc = sparts
-        est = lambda st: np.asarray(
-            swakde.swakde_query_batch(st, params, qs, cfg))
-        empty = swakde.swakde_init(cfg)._replace(t=sa.t)
-        np.testing.assert_array_equal(est(swakde.swakde_merge(sa, empty,
-                                                              cfg)), est(sa))
-        lhs = swakde.swakde_merge(swakde.swakde_merge(sa, sb, cfg), sc, cfg)
-        rhs = swakde.swakde_merge(sa, swakde.swakde_merge(sb, sc, cfg), cfg)
-        # no expiry -> the bucket-union canonicalises: estimates bit-exact
-        np.testing.assert_array_equal(est(lhs), est(rhs))
-        # ... and equal to one sketch over the whole stream
-        whole = swakde.swakde_update_chunk(swakde.swakde_init(cfg), params,
-                                           xs, cfg)
-        np.testing.assert_array_equal(est(lhs), est(whole))
-
-
-def test_merge_counter_saturation():
-    """Counter-overflow audit (PR-5 satellite): every merge combines its
-    stream counters through core.util.saturating_add — near-INT32_MAX
-    inputs clamp instead of wrapping negative."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from repro.core import lsh, race, sann
-    from repro.core.util import _INT32_MAX
-
-    big = int(_INT32_MAX) - 5
-    a = race.RACEState(counts=jnp.zeros((2, 4), jnp.int32),
-                       n=jnp.int32(big))
-    b = race.RACEState(counts=jnp.ones((2, 4), jnp.int32),
-                       n=jnp.int32(100))
-    m = race.race_merge(a, b)
-    assert int(m.n) == int(_INT32_MAX)          # clamped, not wrapped
-    assert int(race.race_merge(b, a).n) == int(_INT32_MAX)
-
-    cfg, params, empty = sann.sann_init(
-        sann.SANNConfig(dim=4, n_max=100, eta=0.5, r=0.5, c=2.0, w=1.0,
-                        L=2, k=2), jax.random.PRNGKey(0))
-    xs = jax.random.normal(jax.random.PRNGKey(1), (32, 4))
-    sa = sann.sann_insert_batch(empty, params, xs[:16],
-                                jax.random.PRNGKey(2), cfg)
-    sb = sann.sann_insert_batch(empty, params, xs[16:],
-                                jax.random.PRNGKey(3), cfg)
-    sa = sa._replace(n_seen=jnp.int32(big))
-    merged = sann.sann_merge(sa, sb, params, cfg)
-    assert int(merged.n_seen) == int(_INT32_MAX)
-    # sanity: un-saturated counters still add exactly
-    assert int(sann.sann_merge(
-        sa._replace(n_seen=jnp.int32(16)), sb, params, cfg).n_seen) == 32
-
-
-def test_sann_merge_disjoint_streams():
-    """`sann_merge` semantics at the core level: two workers ingest
-    disjoint halves of a stream under a shared per-point key schedule; the
-    merged sketch equals a single sketch fed the canonical stamp
-    interleaving — bitwise (except the stamp clocks) without eviction, and
-    with eviction (union > capacity) the live point set, tombstone
-    consistency and nearest-neighbor answers still match."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from repro.core import sann
-
-    def ingest_perpoint(state, params, rows, keys, cfg):
-        for r, k in zip(rows, keys):
-            state = sann.sann_insert(state, params, jnp.asarray(r), k, cfg)
-        return state
-
-    def run_case(n_points, cfg_kw, expect_evict):
-        cfg, params, empty = sann.sann_init(
-            sann.SANNConfig(**cfg_kw), jax.random.PRNGKey(0))
-        rng = np.random.default_rng(0)
-        data = rng.uniform(0, 1, (n_points, cfg.dim)).astype(np.float32)
-        master = jax.random.PRNGKey(5)
-        gkeys = [jax.random.fold_in(master, i) for i in range(n_points)]
-        pid = np.arange(n_points) % 2            # round-robin split: the
-        # canonical (local idx, worker) interleaving == original order
-        wa = ingest_perpoint(empty, params, data[pid == 0],
-                             [gkeys[i] for i in range(n_points)
-                              if pid[i] == 0], cfg)
-        wb = ingest_perpoint(empty, params, data[pid == 1],
-                             [gkeys[i] for i in range(n_points)
-                              if pid[i] == 1], cfg)
-        ref = ingest_perpoint(empty, params, data, gkeys, cfg)
-        m = sann.sann_merge(wa, wb, params, cfg)
-
-        live = lambda st: sorted(
-            map(tuple, np.asarray(st.points)[np.asarray(st.valid)].tolist()))
-        assert live(m) == live(ref), "live point sets differ"
-        # tombstone consistency: every table entry points at a live slot
-        tb = np.asarray(m.tables)
-        assert np.asarray(m.valid)[tb[tb >= 0]].all()
-        assert int(m.n_stored) == int(np.asarray(m.valid).sum())
-        assert int(m.n_seen) == n_points
-
-        qs = jnp.asarray(data[:8] + 0.01)
-        rm = sann.sann_query_batch(m, params, qs, cfg)
-        rr = sann.sann_query_batch(ref, params, qs, cfg)
-        np.testing.assert_allclose(np.asarray(rm.distance),
-                                   np.asarray(rr.distance), atol=1e-5)
-        np.testing.assert_array_equal(np.asarray(rm.found),
-                                      np.asarray(rr.found))
-        if not expect_evict:
-            # no eviction: full bitwise equality except the stamp clocks
-            for name, (u, v) in zip(m._fields, zip(m, ref)):
-                if name == "stamps":
-                    continue
-                np.testing.assert_array_equal(np.asarray(u), np.asarray(v),
-                                              err_msg=f"field {name!r}")
-            np.testing.assert_array_equal(np.asarray(rm.index),
-                                          np.asarray(rr.index))
-        else:
-            assert int(m.n_stored) == cfg.capacity, "union must evict"
-
-    # eta=0, stream < capacity: exact case
-    run_case(200, dict(dim=6, n_max=100, eta=0.0, r=0.4, c=2.0, w=1.0,
-                       L=4, k=2, bucket_cap=4), expect_evict=False)
-    # eta=0, stream > capacity: union eviction + tombstones (capacity =
-    # max(64, 4 * 16^1.0) = 64 < 300 kept)
-    run_case(300, dict(dim=6, n_max=16, eta=0.0, r=0.4, c=2.0, w=1.0,
-                       L=4, k=2, bucket_cap=4, capacity_slack=1.0),
-             expect_evict=True)
-
-
-def test_sann_merge_fold_associative_stored_set():
-    """K-way fold associativity at the stored-set level, *with* eviction:
-    folding sann_merge in any grouping over 3 workers keeps the same live
-    point set (the newest-capacity rule commutes with folding), matching
-    the newest-capacity of the canonical interleaved union."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from repro.core import sann
-
-    cfg, params, empty = sann.sann_init(sann.SANNConfig(
-        dim=6, n_max=16, eta=0.0, r=0.4, c=2.0, w=1.0, L=4, k=2,
-        bucket_cap=4, capacity_slack=1.0), jax.random.PRNGKey(0))
-    assert cfg.capacity == 64
-    rng = np.random.default_rng(1)
-    data = rng.uniform(0, 1, (270, 6)).astype(np.float32)   # > capacity
-    key = jax.random.PRNGKey(7)
-    parts = [sann.sann_insert_batch(empty, params, jnp.asarray(data[w::3]),
-                                    key, cfg) for w in range(3)]
-    a, b, c = parts
-    m = lambda x, y: sann.sann_merge(x, y, params, cfg)
-    lhs = m(m(a, b), c)
-    rhs = m(a, m(b, c))
-    live = lambda st: sorted(
-        map(tuple, np.asarray(st.points)[np.asarray(st.valid)].tolist()))
-    assert int(lhs.n_stored) == cfg.capacity        # eviction happened
-    assert live(lhs) == live(rhs)
-    # and both equal the newest-capacity of the stamp-interleaved union:
-    # stamps are the per-worker local indices, ties broken worker-first,
-    # so the newest 64 points of the (local_idx, worker) order survive.
-    order = []
-    for j in range(90):
-        for w in range(3):
-            order.append(data[w::3][j])
-    expect = sorted(map(tuple, np.asarray(order[-cfg.capacity:],
-                                          np.float32).tolist()))
-    assert live(lhs) == expect
-
-
 def test_sharded_sann_merge_matches_single_device():
-    out = _run("""
+    out = run_script("""
         import dataclasses, jax, numpy as np
         from repro.core import sann
         from repro.parallel import sketch_sharding as ss
@@ -671,16 +246,16 @@ def test_sharded_sann_merge_matches_single_device():
             dim=8, n_max=500, eta=0.2, r=0.4, c=2.0, w=1.0, L=8, k=2,
             bucket_cap=4), jax.random.PRNGKey(0))
         xs = jax.random.normal(jax.random.PRNGKey(1), (256, 8))
-        a = sann.sann_insert_batch(empty, params, xs[:128],
-                                   jax.random.PRNGKey(2), cfg)
-        b = sann.sann_insert_batch(empty, params, xs[128:],
-                                   jax.random.PRNGKey(3), cfg)
-        single = sann.sann_merge(a, b, params, cfg)
+        insert = jitted(sann.sann_insert_batch, cfg=cfg)
+        a = insert(empty, params, xs[:128], jax.random.PRNGKey(2))
+        b = insert(empty, params, xs[128:], jax.random.PRNGKey(3))
+        single = jitted(sann.sann_merge, cfg=cfg)(a, b, params)
 
         ctx = ss.make_sketch_ctx(ss.make_sketch_mesh(8))
         a8, params8 = ss.shard_sann(a, params, ctx)
         b8, _ = ss.shard_sann(b, params, ctx)
-        merged8 = ss.sharded_sann_merge(a8, b8, params8, cfg, ctx)
+        merged8 = jitted(ss.sharded_sann_merge, cfg=cfg, ctx=ctx)(a8, b8,
+                                                                  params8)
         for name, (u, v) in zip(single._fields, zip(single, merged8)):
             np.testing.assert_array_equal(
                 np.asarray(u), np.asarray(v), err_msg=name)
@@ -694,7 +269,7 @@ def test_sharded_sann_recovery_matches_single_run():
     crash-recovers onto the mesh (`_place_state` re-shards the
     host-restored snapshot) bit-identically to the uninterrupted sharded
     run."""
-    out = _run("""
+    out = run_script("""
         import tempfile
         import numpy as np, jax
         from repro.serve.retrieval import RetrievalConfig, RetrievalService
@@ -733,7 +308,7 @@ def test_sharded_fleet_matches_single_device():
     (T/8 whole sketches per device, params replicated, routing local)
     ingests a mixed chunk and answers mixed-tenant queries bit-identically
     to the unsharded vmapped fleet."""
-    out = _run("""
+    out = run_script("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import fleet, lsh, race, sann, swakde
         from repro.parallel import sketch_sharding as ss
@@ -749,35 +324,37 @@ def test_sharded_fleet_matches_single_device():
         # RACE fleet
         params = lsh.init_srp(jax.random.PRNGKey(0), d, L=6, k=3,
                               n_buckets=32)
-        ref = fleet.race_fleet_ingest(
+        ref = jitted(fleet.race_fleet_ingest)(
             fleet.fleet_broadcast(race.race_init(6, 32), T), params, xs,
             tids)
         st, p = ss.shard_fleet(
             fleet.fleet_broadcast(race.race_init(6, 32), T), params, ctx)
-        st = ss.sharded_race_fleet_ingest(st, p, xs, tids, ctx)
+        st = jitted(ss.sharded_race_fleet_ingest, ctx=ctx)(st, p, xs, tids)
         assert (np.asarray(st.counts) == np.asarray(ref.counts)).all()
         assert (np.asarray(st.n) == np.asarray(ref.n)).all()
         np.testing.assert_array_equal(
-            np.asarray(ss.sharded_race_fleet_query(st, p, qs, qt, ctx)),
-            np.asarray(fleet.race_fleet_query(ref, params, qs, qt)))
+            np.asarray(jitted(ss.sharded_race_fleet_query, ctx=ctx)(
+                st, p, qs, qt)),
+            np.asarray(jitted(fleet.race_fleet_query)(ref, params, qs, qt)))
 
         # SW-AKDE fleet (window < per-tenant stream: expiry crosses shards)
         cfg = swakde.SWAKDEConfig(L=4, W=32, window=8, eh_eps=0.2)
         sp = lsh.init_pstable(jax.random.PRNGKey(1), d, 4, 2, 1.0, 32)
         cap = int(np.bincount(np.asarray(tids), minlength=T).max())
-        sref = fleet.swakde_fleet_ingest(
+        sref = jitted(fleet.swakde_fleet_ingest, cfg=cfg, cap=cap)(
             fleet.fleet_broadcast(swakde.swakde_init(cfg), T), sp, xs,
-            tids, cfg, cap)
+            tids)
         sst, spp = ss.shard_fleet(
             fleet.fleet_broadcast(swakde.swakde_init(cfg), T), sp, ctx)
-        sst = ss.sharded_swakde_fleet_ingest(sst, spp, xs, tids, cfg, cap,
-                                             ctx)
+        sst = jitted(ss.sharded_swakde_fleet_ingest, cfg=cfg, cap=cap,
+                     ctx=ctx)(sst, spp, xs, tids)
         for a, b in zip(jax.tree.leaves(sst), jax.tree.leaves(sref)):
             assert (np.asarray(a) == np.asarray(b)).all()
         np.testing.assert_array_equal(
-            np.asarray(ss.sharded_swakde_fleet_query(sst, spp, qs, qt, cfg,
-                                                     ctx)),
-            np.asarray(fleet.swakde_fleet_query(sref, sp, qs, qt, cfg)))
+            np.asarray(jitted(ss.sharded_swakde_fleet_query, cfg=cfg,
+                              ctx=ctx)(sst, spp, qs, qt)),
+            np.asarray(jitted(fleet.swakde_fleet_query, cfg=cfg)(
+                sref, sp, qs, qt)))
 
         # S-ANN fleet (eta > 0: the per-tenant chunk keys shard with the
         # tenant axis; inf/-1 top-k padding must survive the psum combine)
@@ -786,23 +363,23 @@ def test_sharded_fleet_matches_single_device():
         scfg, spar, sempty = sann.sann_init(scfg, jax.random.PRNGKey(2))
         keys = jnp.stack([jax.random.fold_in(jax.random.PRNGKey(5), t)
                           for t in range(T)])
-        aref = fleet.sann_fleet_ingest(
-            fleet.fleet_broadcast(sempty, T), spar, xs, tids, keys, scfg,
-            cap)
+        aref = jitted(fleet.sann_fleet_ingest, cfg=scfg, cap=cap)(
+            fleet.fleet_broadcast(sempty, T), spar, xs, tids, keys)
         ast, app = ss.shard_fleet(fleet.fleet_broadcast(sempty, T), spar,
                                   ctx)
-        ast = ss.sharded_sann_fleet_ingest(ast, app, xs, tids, keys, scfg,
-                                           cap, ctx)
+        ast = jitted(ss.sharded_sann_fleet_ingest, cfg=scfg, cap=cap,
+                     ctx=ctx)(ast, app, xs, tids, keys)
         for a, b in zip(jax.tree.leaves(ast), jax.tree.leaves(aref)):
             assert (np.asarray(a) == np.asarray(b)).all()
-        ids, dists = ss.sharded_sann_fleet_query_topk(ast, app, qs, qt,
-                                                      scfg, ctx, topk=8)
-        wi, wd = fleet.sann_fleet_query_topk(aref, spar, qs, qt, scfg,
-                                             topk=8)
+        ids, dists = jitted(ss.sharded_sann_fleet_query_topk, cfg=scfg,
+                            ctx=ctx, topk=8)(ast, app, qs, qt)
+        wi, wd = jitted(fleet.sann_fleet_query_topk, cfg=scfg, topk=8)(
+            aref, spar, qs, qt)
         np.testing.assert_array_equal(np.asarray(ids), np.asarray(wi))
         np.testing.assert_array_equal(np.asarray(dists), np.asarray(wd))
-        res = ss.sharded_sann_fleet_query(ast, app, qs, qt, scfg, ctx)
-        want = fleet.sann_fleet_query(aref, spar, qs, qt, scfg)
+        res = jitted(ss.sharded_sann_fleet_query, cfg=scfg, ctx=ctx)(
+            ast, app, qs, qt)
+        want = jitted(fleet.sann_fleet_query, cfg=scfg)(aref, spar, qs, qt)
         for a, b in zip(res, want):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
         print("FLEET_SHARDED_OK")

@@ -9,13 +9,8 @@ semantics and serve as oracles; these tests pin the fused engine to them —
 indices / found flags / counts exactly, distances to 1e-5, RACE counts and
 SW-AKDE estimates exactly — including ring-wrapped and evicted stores,
 duplicate-slot dedup, batch sizes not divisible by the service query
-block, and the 8-device sharded path.
+block.  The 8-device sharded query paths are in test_query_sharded.py.
 """
-import os
-import subprocess
-import sys
-import textwrap
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -245,105 +240,3 @@ def test_empty_query_batch():
     kde = KDEService(KDEServiceConfig(dim=d, L=4, W=16, window=50))
     assert kde.query(np.zeros((0, d), np.float32)).shape == (0,)
     assert kde.density(np.zeros((0, d), np.float32)).shape == (0,)
-
-
-def _run(body: str) -> str:
-    script = ("import os\n"
-              "os.environ['XLA_FLAGS'] = "
-              "'--xla_force_host_platform_device_count=8'\n" +
-              textwrap.dedent(body))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
-    env.pop("JAX_PLATFORMS", None)
-    r = subprocess.run([sys.executable, "-c", script], capture_output=True,
-                       text=True, timeout=900, env=env)
-    assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-3000:])
-    return r.stdout
-
-
-def test_sharded_fused_sann_matches_single_device():
-    """The sharded S-ANN query paths reuse the fused batched reductions per
-    table shard; results must stay bitwise equal to the single-device fused
-    engine (and therefore to the per-query oracles) on 8 forced host
-    devices."""
-    out = _run("""
-        import jax, jax.numpy as jnp, numpy as np
-        from repro.core import sann
-        from repro.parallel import sketch_sharding as ss
-
-        d = 12
-        ctx = ss.make_sketch_ctx(ss.make_sketch_mesh(8))
-        cfg = sann.SANNConfig(dim=d, n_max=2000, eta=0.35, r=0.8, c=2.0,
-                              w=1.6, L=16, k=4)
-        cfg, params, st0 = sann.sann_init(cfg, jax.random.PRNGKey(0))
-        stream = jnp.asarray(np.random.default_rng(1).uniform(
-            0, 1, (600, d)).astype(np.float32))
-        key = jax.random.PRNGKey(2)
-        qs = stream[:9] + 0.01
-        ref = sann.sann_insert_batch(st0, params, stream, key, cfg)
-        st, p = ss.shard_sann(st0, params, ctx)
-        st = ss.sharded_sann_insert_batch(st, p, stream, key, cfg, ctx)
-        r1 = sann.sann_query_batch(ref, params, qs, cfg)
-        r8 = ss.sharded_sann_query_batch(st, p, qs, cfg, ctx)
-        for nm, a, b in zip(r1._fields, r8, r1):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
-                                          err_msg=nm)
-        t1 = sann.sann_query_topk_batch(ref, params, qs, cfg, topk=10)
-        t8 = ss.sharded_sann_query_topk_batch(st, p, qs, cfg, ctx, topk=10)
-        np.testing.assert_array_equal(np.asarray(t8[0]), np.asarray(t1[0]))
-        np.testing.assert_array_equal(np.asarray(t8[1]), np.asarray(t1[1]))
-        print("FUSED_SHARDED_SANN_OK")
-    """)
-    assert "FUSED_SHARDED_SANN_OK" in out
-
-
-def test_sharded_fused_race_matches_single_device():
-    out = _run("""
-        import jax, jax.numpy as jnp, numpy as np
-        from repro.core import lsh, race
-        from repro.parallel import sketch_sharding as ss
-
-        d, L, W = 12, 16, 32
-        ctx = ss.make_sketch_ctx(ss.make_sketch_mesh(8))
-        stream = jnp.asarray(np.random.default_rng(1).uniform(
-            0, 1, (600, d)).astype(np.float32))
-        rp = lsh.init_srp(jax.random.PRNGKey(3), d, L=L, k=3, n_buckets=W)
-        rref = race.race_update_batch(race.race_init(L, W), rp, stream)
-        rst, rpp = ss.shard_race(race.race_init(L, W), rp, ctx)
-        rst = ss.sharded_race_update_batch(rst, rpp, stream, ctx)
-        qq = stream[:40]
-        np.testing.assert_array_equal(
-            np.asarray(ss.sharded_race_query_batch(rst, rpp, qq, ctx)),
-            np.asarray(race.race_query_batch(rref, rp, qq)))
-        print("FUSED_SHARDED_RACE_OK")
-    """)
-    assert "FUSED_SHARDED_RACE_OK" in out
-
-
-def test_sharded_fused_swakde_matches_single_device():
-    """Both fused branches (B < W gather / B ≥ W grid precompute) inside
-    the shard_map body must stay bitwise equal to single-device."""
-    out = _run("""
-        import jax, jax.numpy as jnp, numpy as np
-        from repro.core import lsh, swakde
-        from repro.parallel import sketch_sharding as ss
-
-        d = 12
-        ctx = ss.make_sketch_ctx(ss.make_sketch_mesh(8))
-        stream = jnp.asarray(np.random.default_rng(1).uniform(
-            0, 1, (250, d)).astype(np.float32))
-        scfg = swakde.SWAKDEConfig(L=8, W=24, window=120, eh_eps=0.15)
-        sp = lsh.init_srp(jax.random.PRNGKey(4), d, L=8, k=2, n_buckets=24)
-        sref = swakde.swakde_update_chunk(swakde.swakde_init(scfg), sp,
-                                          stream, scfg)
-        sst, spp = ss.shard_swakde(swakde.swakde_init(scfg), sp, ctx)
-        sst = ss.sharded_swakde_update_chunk(sst, spp, stream, scfg, ctx)
-        for nq in (5, 40):
-            qq = stream[:nq]
-            np.testing.assert_array_equal(
-                np.asarray(ss.sharded_swakde_query_batch(sst, spp, qq, scfg,
-                                                         ctx)),
-                np.asarray(swakde.swakde_query_batch(sref, sp, qq, scfg)))
-        print("FUSED_SHARDED_SWAKDE_OK")
-    """)
-    assert "FUSED_SHARDED_SWAKDE_OK" in out
