@@ -186,9 +186,10 @@ def swakde_fleet_ingest(stacked: SWAKDEState, params, xs: jax.Array,
     codes = lsh.hash_points(params, xs)                         # (B, L)
     route = route_chunk(tids, T, cap)
     codes_t = codes[route.take]                                 # (T, cap, L)
+    reach = lsh.code_range(params)
 
     def one(st, cb, vb, cnt):
-        prep = swakde_prepare_from_codes(cb, cfg, mask=vb)
+        prep = swakde_prepare_from_codes(cb, cfg, reach, mask=vb)
         return swakde_commit_chunk(st, prep, cfg, count=cnt)
 
     return jax.vmap(one)(stacked, codes_t, route.valid, route.counts)

@@ -102,6 +102,18 @@ def pstable_hash(params: PStableParams, x: jax.Array) -> jax.Array:
     return _fold(h, params.mix, params.n_buckets)
 
 
+def code_range(params) -> int | None:
+    """Distinct bucket ids one row can reach, or None if unbounded.
+
+    SRP packs k sign bits, so a row has 2^k raw values and `_fold` maps them
+    to at most 2^k buckets.  p-stable raw hashes are unbounded integers."""
+    if isinstance(params, SRPParams):
+        return 2 ** params.k
+    if isinstance(params, PStableParams):
+        return None
+    raise TypeError(type(params))
+
+
 def hash_points(params, x: jax.Array) -> jax.Array:
     if isinstance(params, SRPParams):
         return srp_hash(params, x)

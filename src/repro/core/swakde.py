@@ -129,12 +129,20 @@ class SWAKDEPrep(NamedTuple):
     seg_first: jax.Array  # (L, SW) int32 — first sorted position of segment
 
 
+def segment_width(C: int, cfg: SWAKDEConfig,
+                  code_range: Optional[int]) -> int:
+    """Segments per row of a prepared ``C``-point chunk: the most distinct
+    cells one row can hit, ``min(C, W)``, further bounded by the hash's
+    reachable codes (`lsh.code_range`: 2^k for SRP) when known."""
+    return min(C, cfg.W) if code_range is None else min(C, cfg.W, code_range)
+
+
 def swakde_prepare_chunk(params, xs: jax.Array, cfg: SWAKDEConfig,
                          mask: Optional[jax.Array] = None) -> SWAKDEPrep:
     """Prepare phase for ``xs (C, d)``: one hash matmul, then per row a
-    stable sort of the chunk's codes into ≤ min(C, W) cell segments (each
-    hit cell's points form a contiguous run in stream order).  All of it is
-    state-independent — the embarrassingly parallel half of an update.
+    stable sort of the chunk's codes into `segment_width` cell segments
+    (each hit cell's points form a contiguous run in stream order).  All of
+    it is state-independent — the embarrassingly parallel half of an update.
 
     ``mask`` (optional, (C,) bool) drops rows from the chunk: masked-out
     rows are hashed to the sentinel code ``W`` — they sort last, land in a
@@ -144,18 +152,25 @@ def swakde_prepare_chunk(params, xs: jax.Array, cfg: SWAKDEConfig,
     then assigns live rows exactly the offsets the unpadded chunk would
     get.  This is the tenant-fleet padding contract (`core.fleet`); pair it
     with ``swakde_commit_chunk(..., count=count)``."""
-    return swakde_prepare_from_codes(lsh.hash_points(params, xs), cfg, mask)
+    return swakde_prepare_from_codes(lsh.hash_points(params, xs), cfg,
+                                     lsh.code_range(params), mask)
 
 
 def swakde_prepare_from_codes(codes: jax.Array, cfg: SWAKDEConfig,
+                              code_range: Optional[int],
                               mask: Optional[jax.Array] = None) -> SWAKDEPrep:
     """`swakde_prepare_chunk` with the hash codes ``(C, L)`` supplied by the
     caller — the sort-into-segments half alone.  The tenant-routed fleet
     ingest (`core.fleet`) hashes one mixed multi-tenant chunk with the
     shared params in a single matmul and feeds each tenant's routed code
-    block through this entry point."""
+    block through this entry point.
+
+    ``code_range`` (static) bounds the distinct codes a row can hold
+    (`lsh.code_range` of the params that made ``codes``); None leaves the
+    segment axis at ``min(C, W)``.  A masked chunk's sentinel segment may
+    then fall one past the axis and be dropped, which is what it needs."""
     C = codes.shape[0]
-    SW = min(C, cfg.W)                       # max distinct cells hit per row
+    SW = segment_width(C, cfg, code_range)   # max distinct cells hit per row
     if mask is not None:
         codes = jnp.where(mask[:, None], codes, jnp.int32(cfg.W))
     pos = jnp.arange(C, dtype=jnp.int32)
@@ -164,7 +179,7 @@ def swakde_prepare_from_codes(codes: jax.Array, cfg: SWAKDEConfig,
         order = jnp.argsort(codes_l, stable=True)
         sc = codes_l[order]
         is_start = jnp.concatenate([jnp.ones((1,), bool), sc[1:] != sc[:-1]])
-        seg_id = jnp.cumsum(is_start).astype(jnp.int32) - 1   # (C,) < SW
+        seg_id = jnp.cumsum(is_start).astype(jnp.int32) - 1   # (C,) ≤ SW
         seg_len = jnp.zeros((SW,), jnp.int32).at[seg_id].add(1, mode="drop")
         seg_code = jnp.full((SW,), cfg.W, jnp.int32).at[seg_id].set(
             sc, mode="drop")
@@ -172,7 +187,9 @@ def swakde_prepare_from_codes(codes: jax.Array, cfg: SWAKDEConfig,
             pos, mode="drop")
         # Sentinel segments (unused slots *and* the masked-row segment)
         # carry code W and must stay empty so the commit never drains them;
-        # real codes are < W, so this is a no-op without a mask.
+        # real codes are < W, so this is a no-op without a mask.  Only the
+        # masked-row segment can get id SW (all SW live codes present), and
+        # the "drop" scatters above leave it out.
         seg_len = jnp.where(seg_code == cfg.W, 0, seg_len)
         return order.astype(jnp.int32), seg_code, seg_len, seg_first
 
@@ -201,6 +218,14 @@ def swakde_commit_chunk(state: SWAKDEState, prep: SWAKDEPrep,
     Pair it with a prefix ``mask`` on `swakde_prepare_chunk` — masked
     chunks fold only their live prefix, and the clock must advance by the
     live count (the tenant-fleet padding contract, `core.fleet`)."""
+    return swakde_commit_chunk_passes(state, prep, cfg, count)[0]
+
+
+def swakde_commit_chunk_passes(
+        state: SWAKDEState, prep: SWAKDEPrep, cfg: SWAKDEConfig,
+        count: Optional[jax.Array] = None) -> tuple[SWAKDEState, jax.Array]:
+    """`swakde_commit_chunk`, also returning the number of segment passes
+    its while loop ran (int32 scalar; 1 when no segment splits)."""
     eh = cfg.eh_config()
     C = prep.order.shape[1]
 
@@ -217,18 +242,18 @@ def swakde_commit_chunk(state: SWAKDEState, prep: SWAKDEPrep,
         return (carry[2] < prep.seg_len).any()
 
     def body(carry):
-        cts, cnum, dn = carry
-        return kernel_ops.swakde_segment_pass(
+        cts, cnum, dn, n = carry
+        return (*kernel_ops.swakde_segment_pass(
             cts, cnum, dn, sorted_ts, prep.seg_first, prep.seg_len,
             window=cfg.window, maxb=eh.max_buckets_per_level,
-            n_levels=eh.levels, cap=cfg.heavy_cell_cap)
+            n_levels=eh.levels, cap=cfg.heavy_cell_cap), n + 1)
 
-    cell_ts, cell_num, _ = lax.while_loop(
-        cond, body, (cell_ts, cell_num, done))
+    cell_ts, cell_num, _, passes = lax.while_loop(
+        cond, body, (cell_ts, cell_num, done, jnp.int32(0)))
     ts = state.ts.at[rows, prep.seg_code].set(cell_ts, mode="drop")
     num = state.num.at[rows, prep.seg_code].set(cell_num, mode="drop")
-    return SWAKDEState(ts=ts, num=num,
-                       t=saturating_add(state.t, C if count is None else count))
+    return SWAKDEState(ts=ts, num=num, t=saturating_add(
+        state.t, C if count is None else count)), passes
 
 
 def swakde_update_chunk(state: SWAKDEState, params, xs: jax.Array,

@@ -126,6 +126,7 @@ class KDEService(SketchEngine):
                                            w=cfg.w, n_buckets=cfg.W)
         else:
             raise ValueError(cfg.hash_family)
+        self._segment_width: Optional[int] = None
         super().__init__(ingest_chunk=cfg.ingest_chunk,
                          query_block=cfg.query_block,
                          pipelined=cfg.pipelined,
@@ -164,6 +165,7 @@ class KDEService(SketchEngine):
         return self._prepare_fn(chunk)
 
     def _commit(self, state: swakde.SWAKDEState, prep: swakde.SWAKDEPrep):
+        self._segment_width = prep.seg_code.shape[1]
         return self._commit_fn(state, prep)
 
     def _place_state(self, state: swakde.SWAKDEState) -> swakde.SWAKDEState:
@@ -198,6 +200,13 @@ class KDEService(SketchEngine):
         self._durable_mutate(
             persist.KIND_CLOCK, {"t": np.asarray(t, np.int32)},
             lambda st: st._replace(t=jnp.maximum(st.t, jnp.int32(t))))
+
+    def stats(self) -> dict:
+        """`SketchEngine.stats` plus ``segment_width``: the segments per
+        row of the prep that the last commit ran over (None before one)."""
+        out = super().stats()
+        out["segment_width"] = self._segment_width
+        return out
 
     @property
     def num_shards(self) -> int:

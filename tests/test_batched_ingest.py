@@ -257,3 +257,19 @@ def test_kde_service_matches_direct_stream():
         direct, svc.params, jnp.asarray(data[:4]), svc.sketch_cfg))
     np.testing.assert_allclose(q, dq)
     assert (svc.density(data[:4]) >= 0).all()
+
+
+@pytest.mark.parametrize("family,want", [("srp", 4), ("pstable", 32)])
+def test_kde_service_segment_width(family, want):
+    """`stats()["segment_width"]` is the width of the prep the last commit
+    ran over: 2^k (k = 2) for SRP and min(ingest_chunk, W) for p-stable on
+    a full chunk; a 3-row remainder chunk has at most 3 segments."""
+    from repro.serve.kde_service import KDEService, KDEServiceConfig
+    svc = KDEService(KDEServiceConfig(dim=8, L=6, W=32, window=80, k=2,
+                                      hash_family=family, ingest_chunk=50))
+    assert svc.stats()["segment_width"] is None
+    data = np.random.default_rng(2).normal(0, 1, (53, 8)).astype(np.float32)
+    svc.ingest(data[:50])
+    assert svc.stats()["segment_width"] == want
+    svc.ingest(data[50:])
+    assert svc.stats()["segment_width"] == 3

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import fleet, race, sann, swakde
-from repro.core.lsh import init_pstable, init_srp
+from repro.core.lsh import hash_points, init_pstable, init_srp
 
 import compiled
 
@@ -120,6 +120,34 @@ def test_swakde_fleet_bitexact_with_expiry_at_tenant_boundaries():
             denom = max(min(int(oracle[t].t), cfg.window), 1)
             np.testing.assert_array_equal(np.asarray(kde)[m],
                                           np.asarray(got)[m] / denom)
+
+
+def test_swakde_fleet_srp_sentinel_past_cut_segment_axis():
+    """SRP cuts the segment axis to 2^k.  A padded tenant whose rows hit
+    all 2^k codes puts its pads' sentinel segment one past that axis, where
+    the scatters drop it; the fleet stays bit-identical to the per-tenant
+    loop, across expiring commits."""
+    T, d, k = 2, 5, 2
+    cfg = swakde.SWAKDEConfig(L=4, W=32, window=24, eh_eps=0.2)
+    params = init_srp(jax.random.PRNGKey(12), d, cfg.L, k, cfg.W)
+    stacked = fleet.fleet_broadcast(swakde.swakde_init(cfg), T)
+    oracle = [swakde.swakde_init(cfg) for _ in range(T)]
+    for chunk in range(3):
+        xs, tids = _mixed(T, 80, d, seed=40 + chunk, probs=[0.7, 0.3])
+        counts = np.bincount(tids, minlength=T)
+        cap = int(counts.max())
+        codes = np.asarray(hash_points(params, jnp.asarray(xs[tids == 1])))
+        assert counts[1] < cap, "tenant 1 must carry pads"
+        assert all(len(np.unique(codes[:, l])) == 2 ** k
+                   for l in range(cfg.L)), "every row must hit all 2^k codes"
+        stacked = compiled.swakde_fleet_ingest(
+            stacked, params, jnp.asarray(xs), jnp.asarray(tids, jnp.int32),
+            cfg, cap)
+        for t in range(T):
+            oracle[t] = compiled.swakde_update_chunk(
+                oracle[t], params, jnp.asarray(xs[tids == t]), cfg)
+    _leaves_equal(stacked, fleet.fleet_stack(oracle))
+    assert int(oracle[1].t) > cfg.window, "tenant 1 must actually expire"
 
 
 def test_sann_fleet_bitexact_with_ring_wrap():

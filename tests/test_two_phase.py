@@ -72,6 +72,51 @@ def test_swakde_two_phase_bit_identical(chunk):
     assert _states_equal(st, ref)
 
 
+@pytest.mark.parametrize("family,k,want", [
+    ("srp", 2, 4), ("srp", 6, 32), ("pstable", 2, 32)])
+def test_swakde_prepare_segment_width(family, k, want):
+    """The segment axis holds the most cells one row can hit: min(C, W, 2^k)
+    for SRP (k sign bits per row), min(C, W) for p-stable (unbounded raw
+    hashes).  With 2^k >= W SRP keeps min(C, W) too.  Every point still
+    lands in a segment."""
+    L, C, W = 6, 64, 32
+    cfg = swakde.SWAKDEConfig(L=L, W=W, window=100, eh_eps=0.1)
+    key = jax.random.PRNGKey(0)
+    if family == "srp":
+        p = lsh.init_srp(key, 8, L=L, k=k, n_buckets=W)
+    else:
+        p = lsh.init_pstable(key, 8, L=L, k=k, w=1.0, n_buckets=W)
+    assert want == swakde.segment_width(C, cfg, lsh.code_range(p))
+    xs = jax.random.normal(jax.random.PRNGKey(1), (C, 8))
+    prep = swakde.swakde_prepare_chunk(p, xs, cfg)
+    for a in (prep.seg_code, prep.seg_len, prep.seg_first):
+        assert a.shape == (L, want)
+    assert (np.asarray(prep.seg_len).sum(axis=1) == C).all()
+
+
+_commit_passes = jax.jit(swakde.swakde_commit_chunk_passes, static_argnums=2)
+
+
+@pytest.mark.parametrize("k", [1, 2, 3])
+def test_swakde_cut_commit_multipass_bit_identical(k):
+    """SRP preps cut to 2^k segments commit bit-identically to the per-point
+    path over a stream that fills and expires the window (window < chunk <
+    stream), with commits that take more than one pass."""
+    cfg = swakde.SWAKDEConfig(L=4, W=64, window=60, eh_eps=0.2)
+    p = lsh.init_srp(jax.random.PRNGKey(3), 8, L=cfg.L, k=k, n_buckets=cfg.W)
+    xs = jax.random.normal(jax.random.PRNGKey(4), (400, 8))
+    ref = swakde.swakde_stream(swakde.swakde_init(cfg), p, xs, cfg)
+    st = swakde.swakde_init(cfg)
+    passes = []
+    for i in range(0, 400, 100):
+        prep = swakde.swakde_prepare_chunk(p, xs[i:i + 100], cfg)
+        assert prep.seg_code.shape == (cfg.L, 2 ** k)
+        st, n = _commit_passes(st, prep, cfg)
+        passes.append(int(n))
+    assert max(passes) > 1, passes
+    assert _states_equal(st, ref)
+
+
 def test_swakde_prepare_ahead_of_commits_skewed():
     """Prepare-ahead over the replay loop's worst case (all points in one
     bucket): preps carry only relative sort offsets, so committing them
