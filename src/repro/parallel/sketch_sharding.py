@@ -190,6 +190,39 @@ def shard_sann(state: sann.SANNState, params, ctx: ShardingCtx):
             _put(params, _param_specs(params, ctx), ctx.mesh))
 
 
+def sharded_sann_init(cfg: sann.SANNConfig, key: jax.Array,
+                      ctx: ShardingCtx):
+    """`sann.sann_init` with the empty state built on the mesh: each device
+    allocates only its table block and its replica of the point store, so
+    no device ever holds the whole index (at n_max = 50M the whole tables
+    are 9.7 GB; with a shard beside them they overflow one v5e's HBM)."""
+    if ctx.mesh is None:
+        return sann.sann_init(cfg, key)
+    cfg = cfg.resolved()
+    params = lsh.init_pstable(key, cfg.dim, cfg.L, cfg.k, cfg.w,
+                              cfg.n_buckets)
+    out = jax.tree.map(lambda s: NamedSharding(ctx.mesh, s),
+                       _sann_state_specs(ctx))
+    state = jax.jit(lambda: sann.sann_empty_state(cfg), out_shardings=out)()
+    return cfg, _put(params, _param_specs(params, ctx), ctx.mesh), state
+
+
+def state_bytes_per_device(state) -> dict:
+    """Bytes of ``state`` that one device holds, read off the arrays'
+    addressable shards: ``per_chip`` (the largest per-device total) and
+    ``replicated``, the part of it in arrays every device holds whole.  On
+    one device every array is whole, so ``replicated == per_chip``."""
+    per_dev: dict = {}
+    replicated = 0
+    for x in jax.tree.leaves(state):
+        shards = x.addressable_shards
+        for s in shards:
+            per_dev[s.device] = per_dev.get(s.device, 0) + s.data.nbytes
+        if x.sharding.is_fully_replicated:
+            replicated += shards[0].data.nbytes
+    return {"per_chip": max(per_dev.values()), "replicated": replicated}
+
+
 # ---------------------------------------------------------------------------
 # RACE
 # ---------------------------------------------------------------------------

@@ -111,8 +111,10 @@ class RetrievalService(SketchEngine):
         base = sann.SANNConfig(
             dim=cfg.dim, n_max=cfg.n_max, eta=cfg.eta, r=cfg.r, c=cfg.c,
             w=cfg.w, L=cfg.L, k=cfg.k, bucket_cap=cfg.bucket_cap)
-        self.cfg, self.params, state = sann.sann_init(
-            base, jax.random.PRNGKey(cfg.seed))
+        # The state is built where it lives (on the mesh when sharded).
+        self._ctx = ss.make_service_ctx(cfg.mesh, cfg.num_shards)
+        self.cfg, self.params, state = ss.sharded_sann_init(
+            base, jax.random.PRNGKey(cfg.seed), self._ctx)
         super().__init__(ingest_chunk=cfg.ingest_chunk,
                          query_block=cfg.query_block,
                          pipelined=cfg.pipelined,
@@ -130,10 +132,6 @@ class RetrievalService(SketchEngine):
         self._ingest_key = jax.random.fold_in(
             jax.random.PRNGKey(cfg.seed + 1), cfg.ingest_salt)
 
-        self._ctx = ss.make_service_ctx(cfg.mesh, cfg.num_shards)
-        if self._ctx.mesh is not None:
-            self.state, self.params = ss.shard_sann(self.state, self.params,
-                                                    self._ctx)
         self._prepare_fn = jax.jit(
             lambda xs, key: ss.sharded_sann_prepare_chunk(
                 self.params, xs, key, self.cfg, self._ctx))
@@ -228,6 +226,16 @@ class RetrievalService(SketchEngine):
         L * bucket_cap)``, padded with id -1 / distance inf.  Same snapshot
         and micro-batching semantics as `query`."""
         return self._serve_query("topk", queries)
+
+    def stats(self) -> dict:
+        """`SketchEngine.stats` plus ``shard_state_bytes``: the bytes of
+        index state one chip holds (``per_chip``) and the part that every
+        chip holds alike (``replicated``: the point store, valid mask,
+        stamps and counters when sharded; everything on one device)."""
+        out = super().stats()
+        out["shard_state_bytes"] = ss.state_bytes_per_device(
+            self.snapshot()[0])
+        return out
 
     @property
     def stored(self) -> int:

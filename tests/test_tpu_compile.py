@@ -1,6 +1,9 @@
 """The TPU kernel route: every op that `kernels.dispatch` sends to Pallas on
 a TPU compiles for a v5e chip at the widths the services run
 (`chip_smoke.py`), and the route table names exactly the expected ops.
+The sharded S-ANN ingest of the benchmark's four-chip cell (n_max = 50M,
+``num_shards=4``) compiles for a v5e 2x2 host, with each chip holding
+only its share of the index.
 
 The compiles target a *described* chip (``v5e:2x2`` topology), so no TPU is
 needed; nothing runs.  The topology is described inside a fixture, never at
@@ -11,10 +14,13 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
+from repro.core import lsh, sann
 from repro.kernels import batch_score, cand_score, dispatch, ops, race_update
+from repro.parallel import sketch_sharding as ss
 
 PALLAS_OPS = {"race_hist", "cand_score", "batch_score_topk"}
 XLA_OPS = {"srp_hash", "swakde_segment_pass", "sann_table_scatter",
@@ -113,3 +119,53 @@ def test_tpu_policy_never_interprets(monkeypatch):
     assert all(dispatch.route(op) == "pallas" for op in dispatch.TPU_ROUTE)
     monkeypatch.delenv("REPRO_FORCE_PALLAS")
     assert all(dispatch.route(op) == "xla" for op in dispatch.TPU_ROUTE)
+
+
+def test_sann_50m_sharded_ingest_compiles_for_v5e_2x2(topo, no_compile_cache,
+                                                      monkeypatch):
+    """Empty state, prepare and commit of `RetrievalService` at the
+    `sann-bigann128-50M-4chip` settings on a 2x2 mesh: every program
+    compiles for the chip (its compiler refuses one that overflows HBM),
+    and each chip's state is its 4 tables plus the replicated point store,
+    not the whole 11.6 GB index."""
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    cfg = sann.SANNConfig(dim=128, n_max=50_000_000, eta=0.25, r=1.0,
+                          c=2.0, w=12.0, L=16, k=8,
+                          bucket_cap=16).resolved()
+    ctx = ss.make_sketch_ctx(Mesh(np.asarray(topo.devices).reshape(4),
+                                  (ss.SHARD_AXIS,)))
+
+    def placed(tree, specs):
+        return jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(ctx.mesh, s)),
+            tree, specs)
+
+    state = jax.eval_shape(lambda: sann.sann_empty_state(cfg))
+    params = jax.eval_shape(lambda k: lsh.init_pstable(
+        k, cfg.dim, cfg.L, cfg.k, cfg.w, cfg.n_buckets),
+        jax.random.PRNGKey(0))
+    xs = jax.ShapeDtypeStruct((4096, cfg.dim), jnp.float32)
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    prep = jax.eval_shape(lambda p, x, k: sann.sann_prepare_chunk(
+        p, x, k, cfg), params, xs, key)
+    state_specs = ss._sann_state_specs(ctx)
+    st = placed(state, state_specs)
+    rep = ctx.spec()
+
+    empty = jax.jit(lambda: sann.sann_empty_state(cfg),
+                    out_shardings=jax.tree.map(
+                        lambda s: NamedSharding(ctx.mesh, s), state_specs))
+    per_chip = empty.lower().compile().memory_analysis().output_size_in_bytes
+    whole = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state))
+    replicated = sum(getattr(state, f).size * getattr(state, f).dtype.itemsize
+                     for f in state._fields
+                     if f not in ("tables", "table_ptr"))
+    # the chip's layout pads the small arrays by a few KB
+    assert abs(per_chip - ((whole - replicated) // 4 + replicated)) < 2**20
+
+    jax.jit(lambda p, x, k: ss.sharded_sann_prepare_chunk(
+        p, x, k, cfg, ctx)).lower(
+        placed(params, ss._param_specs(params, ctx)),
+        placed(xs, rep), placed(key, rep)).compile()
+    jax.jit(lambda s, p: ss.sharded_sann_commit_chunk(s, p, cfg, ctx)).lower(
+        st, placed(prep, ss._sann_prep_specs(ctx))).compile()
