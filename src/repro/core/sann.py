@@ -380,8 +380,9 @@ def sann_commit_chunk(state: SANNState, prep: SANNPrep, cfg: SANNConfig,
     # --- ring-buffer appends: prepared segment scatter ---------------------
     # A loser point's entries are appended then tombstoned by the later
     # overwrite of its slot — net effect: the ring cell holds -1.  Entries
-    # are sorted by (row, code), which is what makes this a coalesced
-    # per-row write pass (kernels.ops.sann_table_scatter, DESIGN.md §12).
+    # are sorted by (row, code) with the unkept last, so on a TPU the
+    # append writes the live prefix in place, tile by tile
+    # (kernels.ops.sann_table_scatter, DESIGN.md §12.2).
     val = jnp.where(prep.winner[prep.s_b], slot[prep.s_b], jnp.int32(-1))
     tables = kernel_ops.sann_table_scatter(
         tables, state.table_ptr, prep.s_l, prep.s_c, prep.rank, val,

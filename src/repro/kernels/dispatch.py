@@ -23,16 +23,17 @@ from typing import Optional
 
 import jax
 
-# Implementation of each op on a TPU.  The "xla" entries are kernels the
-# TPU compiler refuses (reasons in ROADMAP.md, Queue 1 item 2):
+# Implementation of each op on a TPU.  "sann_table_scatter" is Pallas
+# because the oracle's flat scatter makes XLA relayout the whole S-ANN
+# table twice around it; the kernel appends in place (and
+# `ops.sann_table_scatter` keeps the oracle where the appends outnumber
+# the tables' cost: `ingest_commit.append_in_place`).  The "xla" entries
+# are kernels the TPU compiler refuses:
 #   * srp_hash — reductions over unsigned integers are not implemented in
 #     Mosaic; the served path hashes with plain jnp (core.lsh) anyway.
 #   * swakde_segment_pass — its body is `ref.swakde_segment_pass_ref`, whose
 #     shape-changing take_along_axis gathers Mosaic cannot lower and whose
 #     (rows, G, C) intermediates exceed VMEM.
-#   * sann_table_scatter — one table row (n_buckets × bucket_cap int32) is
-#     far larger than VMEM, so no block layout fits; 1-D entry blocks and
-#     dynamic scalar reads come next.
 #   * sketch_decode_attn — its (block, 1, dh) K/V blocks break the (8, 128)
 #     block rule on an (S, Hkv, dh) cache.
 TPU_ROUTE = {
@@ -41,7 +42,7 @@ TPU_ROUTE = {
     "batch_score_topk": "pallas",
     "srp_hash": "xla",
     "swakde_segment_pass": "xla",
-    "sann_table_scatter": "xla",
+    "sann_table_scatter": "pallas",
     "sketch_decode_attn": "xla",
 }
 

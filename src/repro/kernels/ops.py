@@ -112,12 +112,13 @@ def swakde_segment_pass(cell_ts, cell_num, done, sorted_ts, seg_first,
 
 def sann_table_scatter(tables, table_ptr, s_l, s_c, rank, val, mask):
     """Sorted-segment ring append into the S-ANN hash tables (entries are
-    sorted by (row, code); see `ref.sann_table_scatter_ref`).  TPU and CPU
-    both run the oracle's XLA scatter (see `dispatch.TPU_ROUTE`)."""
-    if _use_pallas("sann_table_scatter"):
-        return _ic.sann_table_scatter(
-            tables.reshape(*table_ptr.shape, -1), table_ptr, s_l, s_c, rank,
-            val, mask, interpret=_interpret()).reshape(tables.shape)
+    sorted by (row, code); see `ref.sann_table_scatter_ref`).  In place
+    where that is cheaper than the oracle's whole-table scatter: a chunk's
+    appends, not `sann_merge`'s replay of two whole sketches."""
+    if (_use_pallas("sann_table_scatter")
+            and _ic.append_in_place(s_l.shape[0], tables.size)):
+        return _ic.sann_table_scatter(tables, table_ptr, s_l, s_c, rank, val,
+                                      mask, interpret=_interpret())
     return ref.sann_table_scatter_ref(tables, table_ptr, s_l, s_c, rank,
                                       val, mask)
 

@@ -1,4 +1,6 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret mode."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -159,12 +161,11 @@ def test_swakde_segment_pass_matches_ref(seed, cap, block_g):
         np.testing.assert_array_equal(np.asarray(w), np.asarray(g))
 
 
-@pytest.mark.parametrize("seed", [0, 1])
-@pytest.mark.parametrize("mask_frac", [1.0, 0.7])
-def test_sann_table_scatter_matches_ref(seed, mask_frac):
+def _random_scatter_args(seed, mask_frac):
+    """Random sorted appends into random flat-per-row tables."""
     rng = np.random.default_rng(seed)
     L, NB, cap, E = 4, 13, 6, 70
-    tables = rng.integers(-1, 100, (L, NB, cap)).astype(np.int32)
+    tables = rng.integers(-1, 100, (L, NB * cap)).astype(np.int32)
     table_ptr = rng.integers(0, cap, (L, NB)).astype(np.int32)
     s_l = rng.integers(0, L, E).astype(np.int32)
     s_c = rng.integers(0, NB, E).astype(np.int32)
@@ -176,8 +177,90 @@ def test_sann_table_scatter_matches_ref(seed, mask_frac):
         rank[i] = rank[i - 1] + 1 if same else 0
     val = rng.integers(0, 10_000, E).astype(np.int32)
     mask = rng.random(E) < mask_frac
-    a = tuple(jnp.asarray(x) for x in
-              (tables, table_ptr, s_l, s_c, rank, val, mask))
+    return tuple(jnp.asarray(x) for x in
+                 (tables, table_ptr, s_l, s_c, rank, val, mask))
+
+
+def _commit_scatter_args(case, monkeypatch):
+    """The ring append's arguments as `sann_commit_chunk` builds them, from
+    a real commit into a small sketch: ``case`` picks the chunk."""
+    from repro.core import sann
+    seen = []
+
+    def record(*args):
+        seen.append(args)
+        return ref.sann_table_scatter_ref(*args)
+
+    monkeypatch.setattr(sann.kernel_ops, "sann_table_scatter", record)
+    # n_max 50,000: 13,374 point slots; the small ring (270 slots, every
+    # point kept) is nearly full before the chunk, which laps it.
+    small = case == "recycles_slots"
+    cfg = sann.SANNConfig(dim=8, n_max=300 if small else 50_000,
+                          eta=0.0 if small else 0.25, r=0.5, c=2.0, L=4, k=2,
+                          capacity_slack=0.9 if small else 4.0)
+    cfg, params, st = sann.sann_init(cfg, jax.random.PRNGKey(0))
+    B = 16
+    xs = jax.random.uniform(jax.random.PRNGKey(1), (B, 8))
+    if case == "merge":
+        fill = lambda k: sann.sann_insert_chunked(
+            st, params, jax.random.uniform(jax.random.PRNGKey(k), (8192, 8)),
+            jax.random.PRNGKey(k + 1), cfg, chunk=2048)
+        a, b = fill(2), fill(4)
+        seen.clear()
+        sann.sann_merge(a, b, params, cfg)
+        return seen[-1]
+    if case == "recycles_slots":
+        st = sann.sann_insert_chunked(
+            st, params, jax.random.uniform(jax.random.PRNGKey(3),
+                                           (cfg.capacity - 5, 8)),
+            jax.random.PRNGKey(4), cfg, chunk=64)
+        assert int(st.n_stored) == cfg.capacity - 5
+        keep = jnp.ones((B,), bool)
+    elif case == "bucket_overflow":
+        # one point 3 x bucket_cap times: each row's bucket gets 48 appends
+        xs = jnp.broadcast_to(xs[:1], (3 * cfg.bucket_cap, 8))
+        keep = jnp.ones((xs.shape[0],), bool)
+    else:
+        keep = jnp.full((B,), case == "all_kept")
+    seen.clear()
+    sann.sann_commit_chunk(
+        st, sann.sann_prepare_given_keep(params, xs, keep, cfg), cfg)
+    return seen[-1]
+
+
+SCATTER_CASES = ([f"random-{s}-{m}" for s in (0, 1) for m in (1.0, 0.7)]
+                 + ["no_kept", "all_kept", "bucket_overflow",
+                    "recycles_slots", "merge", "vmapped"])
+
+
+@pytest.mark.parametrize("case", SCATTER_CASES)
+def test_sann_table_scatter_matches_ref(case, monkeypatch):
+    """The in-place ring append writes exactly the oracle's tables, for
+    random appends and for the appends of real commits.  ``merge`` is
+    `sann_merge`'s replay of two sketches: its entry count takes
+    `ops.sann_table_scatter` to the oracle, while every chunk-sized case
+    stays in place.  ``vmapped`` is the tenant fleet's form: the commit
+    vmapped over stacked sketches."""
+    if case == "vmapped":
+        a = [jnp.stack(x) for x in zip(*(_random_scatter_args(s, 0.8)
+                                         for s in (2, 3, 4)))]
+        want = jax.vmap(ref.sann_table_scatter_ref)(*a)
+        got = jax.vmap(functools.partial(ic_k.sann_table_scatter,
+                                         interpret=True))(*a)
+        np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+        return
+    if case.startswith("random"):
+        _, seed, frac = case.split("-")
+        a = _random_scatter_args(int(seed), float(frac))
+    else:
+        a = _commit_scatter_args(case, monkeypatch)
+        tables, mask = a[0], a[-1]
+        assert ic_k.append_in_place(mask.shape[0], tables.size) == (
+            case != "merge")
+        if case == "bucket_overflow":
+            assert int(mask.sum()) < mask.shape[0]    # shadowed appends
+        if case == "no_kept":
+            assert not bool(mask.any())
     want = ref.sann_table_scatter_ref(*a)
     got = ic_k.sann_table_scatter(*a, interpret=True)
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))

@@ -1,9 +1,10 @@
 """The TPU kernel route: every op that `kernels.dispatch` sends to Pallas on
 a TPU compiles for a v5e chip at the widths the services run
 (`chip_smoke.py`), and the route table names exactly the expected ops.
-The sharded S-ANN ingest of the benchmark's four-chip cell (n_max = 50M,
-``num_shards=4``) compiles for a v5e 2x2 host, with each chip holding
-only its share of the index.
+The S-ANN commit of the benchmark's cells compiles with its ring append in
+place: at n_max = 1M on one chip, and sharded at n_max = 50M
+(``num_shards=4``) on a v5e 2x2 host, where each chip holds only its share
+of the index.
 
 The compiles target a *described* chip (``v5e:2x2`` topology), so no TPU is
 needed; nothing runs.  The topology is described inside a fixture, never at
@@ -11,6 +12,7 @@ import: one process at a time may load the TPU library, and every test
 worker imports this module.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,12 +21,13 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
 from repro.core import lsh, sann
-from repro.kernels import batch_score, cand_score, dispatch, ops, race_update
+from repro.kernels import (batch_score, cand_score, dispatch, ingest_commit,
+                           ops, race_update)
 from repro.parallel import sketch_sharding as ss
 
-PALLAS_OPS = {"race_hist", "cand_score", "batch_score_topk"}
-XLA_OPS = {"srp_hash", "swakde_segment_pass", "sann_table_scatter",
-           "sketch_decode_attn"}
+PALLAS_OPS = {"race_hist", "cand_score", "batch_score_topk",
+              "sann_table_scatter"}
+XLA_OPS = {"srp_hash", "swakde_segment_pass", "sketch_decode_attn"}
 
 
 @pytest.fixture(scope="module")
@@ -83,6 +86,12 @@ CASES = [
     ("cand_score", "sann-oracle-M48",
      lambda q, c: cand_score.cand_score(q, c, interpret=False),
      [((128,), jnp.float32), ((48, 128), jnp.float32)]),
+    # sann-sift128-1M: 16 tables of 505,964 buckets x 16 slots, a 4,096-point
+    # chunk's 65,536 appends
+    ("sann_table_scatter", "sann-sift128-1M-chunk4096",
+     lambda *a: ingest_commit.sann_table_scatter(*a, interpret=False),
+     [((16, 505964 * 16), jnp.int32), ((16, 505964), jnp.int32)]
+     + [((65536,), jnp.int32)] * 4 + [((65536,), jnp.bool_)]),
 ]
 
 
@@ -121,25 +130,14 @@ def test_tpu_policy_never_interprets(monkeypatch):
     assert all(dispatch.route(op) == "xla" for op in dispatch.TPU_ROUTE)
 
 
-def test_sann_50m_sharded_ingest_compiles_for_v5e_2x2(topo, no_compile_cache,
-                                                      monkeypatch):
-    """Empty state, prepare and commit of `RetrievalService` at the
-    `sann-bigann128-50M-4chip` settings on a 2x2 mesh: every program
-    compiles for the chip (its compiler refuses one that overflows HBM),
-    and each chip's state is its 4 tables plus the replicated point store,
-    not the whole 11.6 GB index."""
-    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
-    cfg = sann.SANNConfig(dim=128, n_max=50_000_000, eta=0.25, r=1.0,
-                          c=2.0, w=12.0, L=16, k=8,
-                          bucket_cap=16).resolved()
-    ctx = ss.make_sketch_ctx(Mesh(np.asarray(topo.devices).reshape(4),
-                                  (ss.SHARD_AXIS,)))
+def _sann_cfg(n_max):
+    """The knobs of the benchmark's S-ANN configurations."""
+    return sann.SANNConfig(dim=128, n_max=n_max, eta=0.25, r=1.0, c=2.0,
+                           w=12.0, L=16, k=8, bucket_cap=16).resolved()
 
-    def placed(tree, specs):
-        return jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=NamedSharding(ctx.mesh, s)),
-            tree, specs)
 
+def _sann_shapes(cfg):
+    """Empty state, params and a 4,096-point chunk's prep, as shapes."""
     state = jax.eval_shape(lambda: sann.sann_empty_state(cfg))
     params = jax.eval_shape(lambda k: lsh.init_pstable(
         k, cfg.dim, cfg.L, cfg.k, cfg.w, cfg.n_buckets),
@@ -148,6 +146,56 @@ def test_sann_50m_sharded_ingest_compiles_for_v5e_2x2(topo, no_compile_cache,
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
     prep = jax.eval_shape(lambda p, x, k: sann.sann_prepare_chunk(
         p, x, k, cfg), params, xs, key)
+    return state, params, xs, key, prep
+
+
+def _assert_append_in_place(compiled, row_entries):
+    """The commit appends through the Pallas kernel, and no while loop of
+    the compiled program carries a table row: XLA's flat scatter into the
+    2-D tables relayouts them in two such loops."""
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    loops = [ln for ln in text.splitlines()
+             if re.search(r"= .*\bwhile\(", ln)]
+    assert not [ln for ln in loops if f",{row_entries}]" in ln]
+
+
+def test_sann_1m_commit_appends_in_place_for_v5e(one_chip, monkeypatch):
+    """`sann_commit_chunk` at `sann-sift128-1M`'s widths on one chip: the
+    ring append runs in place (one Pallas call), so the commit holds less
+    than one table (518,107,136 B) of temporaries and no while loop carries
+    an ``s32[..., 8095424]`` table row."""
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    cfg = _sann_cfg(1_000_000)
+    state, _, _, _, prep = _sann_shapes(cfg)
+    placed = lambda t: jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=one_chip), t)
+    compiled = jax.jit(lambda s, p: sann.sann_commit_chunk(s, p, cfg)).lower(
+        placed(state), placed(prep)).compile()
+    row = cfg.n_buckets * cfg.bucket_cap
+    assert row == 8_095_424
+    assert compiled.memory_analysis().temp_size_in_bytes < cfg.L * row * 4
+    _assert_append_in_place(compiled, row)
+
+
+def test_sann_50m_sharded_ingest_compiles_for_v5e_2x2(topo, no_compile_cache,
+                                                      monkeypatch):
+    """Empty state, prepare and commit of `RetrievalService` at the
+    `sann-bigann128-50M-4chip` settings on a 2x2 mesh: every program
+    compiles for the chip (its compiler refuses one that overflows HBM),
+    and each chip's state is its 4 tables plus the replicated point store,
+    not the whole 11.6 GB index."""
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    cfg = _sann_cfg(50_000_000)
+    ctx = ss.make_sketch_ctx(Mesh(np.asarray(topo.devices).reshape(4),
+                                  (ss.SHARD_AXIS,)))
+
+    def placed(tree, specs):
+        return jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(ctx.mesh, s)),
+            tree, specs)
+
+    state, params, xs, key, prep = _sann_shapes(cfg)
     state_specs = ss._sann_state_specs(ctx)
     st = placed(state, state_specs)
     rep = ctx.spec()
@@ -167,5 +215,13 @@ def test_sann_50m_sharded_ingest_compiles_for_v5e_2x2(topo, no_compile_cache,
         p, x, k, cfg, ctx)).lower(
         placed(params, ss._param_specs(params, ctx)),
         placed(xs, rep), placed(key, rep)).compile()
-    jax.jit(lambda s, p: ss.sharded_sann_commit_chunk(s, p, cfg, ctx)).lower(
-        st, placed(prep, ss._sann_prep_specs(ctx))).compile()
+    commit = jax.jit(lambda s, p: ss.sharded_sann_commit_chunk(
+        s, p, cfg, ctx)).lower(st, placed(prep, ss._sann_prep_specs(ctx))
+                               ).compile()
+    # per device: 4 tables of 152,218,496 entries; the ring append is in
+    # place, so less than one table block of temporaries and no loop
+    # carrying a table row
+    row = cfg.n_buckets * cfg.bucket_cap
+    assert row == 152_218_496
+    assert commit.memory_analysis().temp_size_in_bytes < cfg.L // 4 * row * 4
+    _assert_append_in_place(commit, row)
